@@ -101,6 +101,7 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--cell", default="all")
     args = ap.parse_args()
+    runtime.enable_compile_cache()
 
     if args.cell in ("H1", "all"):
         print("== H1: qwen2.5-14b x train_4k ==")

@@ -164,6 +164,7 @@ def main():
                     help="CI-sized run (fewer steps, smaller eval)")
     ap.add_argument("--out", default="BENCH_qat.json")
     args = ap.parse_args()
+    runtime.enable_compile_cache()
     if args.quick:
         report = bench_qat(args.out, float_steps=150, qat_steps=100,
                            teacher_steps=150, eval_n=1024,

@@ -37,6 +37,8 @@ def main(argv=None) -> int:
     ap.add_argument("--mcu", action="store_true",
                     help="price on the paper's RV32 MCU model")
     args = ap.parse_args(argv)
+    from repro.runtime import enable_compile_cache
+    enable_compile_cache()
 
     cfg = registry.get(args.arch).smoke if args.smoke \
         else registry.get(args.arch).config

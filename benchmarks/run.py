@@ -20,15 +20,20 @@ import jax
 
 
 def bench_kernels():
-    """Pallas kernels (interpret mode on CPU): per-call wall time vs ref."""
+    """Pallas kernels (interpret mode off the chip): per-call wall time vs ref."""
     from repro.kernels import ops, ref
+    from repro.runtime import plan_interpret
+
+    interpret = plan_interpret()
+    mode = "interpret_mode" if interpret else "mosaic"
 
     key = jax.random.PRNGKey(0)
     x = jax.random.normal(key, (256, 512))
     for name, fn in [
-        ("kernel_lut_gelu", lambda: ops.lut_gelu(x)),
+        ("kernel_lut_gelu", lambda: ops.lut_gelu(x, interpret=interpret)),
         ("ref_lut_gelu", lambda: ref.lut_gelu(x)),
-        ("kernel_lut_softmax", lambda: ops.lut_softmax(x)),
+        ("kernel_lut_softmax",
+         lambda: ops.lut_softmax(x, interpret=interpret)),
         ("ref_lut_softmax", lambda: ref.lut_softmax(x)),
     ]:
         fn()
@@ -36,14 +41,14 @@ def bench_kernels():
         for _ in range(5):
             out = fn()
         jax.block_until_ready(out)
-        print(f"{name},{(time.perf_counter()-t0)/5*1e6:.1f},interpret_mode")
+        print(f"{name},{(time.perf_counter()-t0)/5*1e6:.1f},{mode}")
     q = jax.random.normal(key, (1, 4, 128, 64))
     k = jax.random.normal(key, (1, 2, 128, 64))
     t0 = time.perf_counter()
-    out = ops.lut_attention(q, k, k)
+    out = ops.lut_attention(q, k, k, interpret=interpret)
     jax.block_until_ready(out)
     print(f"kernel_lut_attention,{(time.perf_counter()-t0)*1e6:.1f},"
-          "interpret_mode_single_call")
+          f"{mode}_single_call")
 
 
 def bench_backend_sweep(out_path: str = "BENCH_runtime.json",
@@ -218,6 +223,8 @@ def main() -> None:
                     help="append sweep rows to this bench ledger "
                          "(BENCH_history.jsonl) for repro.perf regress")
     args = ap.parse_args()
+    from repro.runtime import enable_compile_cache
+    enable_compile_cache()
 
     if args.backend_sweep:
         print("name,us_per_call,derived")

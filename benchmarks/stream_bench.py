@@ -164,6 +164,7 @@ def main(argv=None):
                     help="append sweep rows to this bench ledger "
                          "(BENCH_history.jsonl) for repro.perf regress")
     args = ap.parse_args(argv)
+    runtime.enable_compile_cache()
 
     base = registry.get(args.arch).smoke
     fcfg = features.FrontendConfig()

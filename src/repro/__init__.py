@@ -1,3 +1,1 @@
 """KWT-Tiny reproduction, grown toward a production-scale jax system."""
-
-from repro import _compat  # noqa: F401  (jax API shims; must import first)

@@ -39,7 +39,7 @@ def _peak_live(jaxpr, count_invar, ctx_bytes=0):
     """High-water-mark live bytes over one jaxpr's equations.
 
     ``count_invar``: per-invar flags — weight operands are excluded (the
-    caller counts them as parameter ROM).  Nested jaxprs (pjit bodies,
+    caller counts them as parameter ROM).  Nested jaxprs (jit bodies,
     custom_vjp primals) are charged against the live set at their call
     site; their invars alias already-counted outer buffers, so only
     their internal temporaries add bytes.

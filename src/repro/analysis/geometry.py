@@ -30,10 +30,16 @@ VMEM_BYTES = 16 * 1024 * 1024      # per-core VMEM (pallas guide)
 _LANE, _SUBLANE = 128, 8           # float32 Mosaic tile
 
 
+def _block_dims(bm) -> tuple:
+    """Block edge sizes (``Blocked`` block dims carry a ``block_size``;
+    squeezed dims are ``None`` and drop out)."""
+    return tuple(int(getattr(d, "block_size", d)) for d in bm.block_shape
+                 if d is not None)
+
+
 def _block_bytes(bm) -> int:
-    shape = [int(d) for d in bm.block_shape if d is not None]
-    dtype = bm.array_shape_dtype.dtype
-    return int(math.prod(shape)) * int(jnp.dtype(dtype).itemsize)
+    dtype = bm.array_aval.dtype
+    return int(math.prod(_block_dims(bm))) * int(jnp.dtype(dtype).itemsize)
 
 
 def _kernel_name(eqn) -> str:
@@ -69,8 +75,8 @@ def check_geometry(engine, x) -> PassResult:
         vmem = 0
         for bm in gm.block_mappings:
             vmem += _block_bytes(bm)
-            block = tuple(int(d) for d in bm.block_shape if d is not None)
-            arr = tuple(int(d) for d in bm.array_shape_dtype.shape)
+            block = _block_dims(bm)
+            arr = tuple(int(d) for d in bm.array_aval.shape)
             if len(block) == len(arr) and any(
                     b > max(a, 1) and b % max(a, 1) != 0
                     for b, a in zip(block, arr)):
@@ -79,8 +85,13 @@ def check_geometry(engine, x) -> PassResult:
                     f"{name}: block {block} is not a tile of array "
                     f"{arr} (pad_to_block/fit_block mismatch)",
                     jw.user_site(eqn)))
-            if len(block) >= 1 and block[-1] % _LANE != 0 or \
-                    len(block) >= 2 and block[-2] % _SUBLANE != 0:
+            # Mosaic takes a block edge that is a tile multiple or the
+            # whole array edge
+            if len(block) == len(arr) and (
+                    len(block) >= 1 and block[-1] % _LANE
+                    and block[-1] != arr[-1] or
+                    len(block) >= 2 and block[-2] % _SUBLANE
+                    and block[-2] != arr[-2]):
                 findings.append(Finding(
                     "warning", "mosaic-tile",
                     f"{name}: block {block} is not {_SUBLANE}x{_LANE}-"

@@ -2,7 +2,7 @@
 
 ``jax.make_jaxpr`` gives the pass pipeline one canonical view of a jitted
 program: a list of equations over typed variables, with call-like
-primitives (``pjit``, ``custom_vjp_call_jaxpr``, ``scan``, ``cond``,
+primitives (``jit``, ``custom_vjp_call_jaxpr``, ``scan``, ``cond``,
 ``pallas_call``, ...) carrying nested jaxprs in their params.  The three
 helpers here are the only places that touch jax internals:
 
@@ -44,10 +44,7 @@ def iter_eqns(jaxpr, depth: int = 0):
 
 def user_frames(eqn):
     """Repo-level stack frames (innermost first) for an equation."""
-    try:
-        return list(source_info_util.user_frames(eqn.source_info))
-    except Exception:
-        return []
+    return list(source_info_util.user_frames(eqn.source_info.traceback))
 
 
 def frame_functions(eqn) -> list:

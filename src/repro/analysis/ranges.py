@@ -186,7 +186,7 @@ def _guarded_uses(var, jaxpr, ctx, depth=0) -> bool:
     """True when every (transitive) use of ``var`` is a ``select_n``
     choice lane (the saturating-guard idiom): the out-of-range value is
     statically dead — some predicate lane replaces it.  ``jnp.where``
-    lowers to ``pjit[name=_where]``, so uses are followed through
+    lowers to ``jit[name=_where]``, so uses are followed through
     call-like primitives into the jaxpr where the select lives."""
     uses = ctx.consumers(jaxpr).get(id(var), [])
     if not uses or depth > 4:
@@ -401,7 +401,7 @@ def _run(jaxpr, env, ctx):
                       "rsqrt", "sqrt", "erf", "sin", "cos", "integer_pow",
                       "pow", "is_finite"):
             out = _elementwise_math(name, eqn, ins)
-        elif name in ("pjit", "closed_call", "custom_vjp_call_jaxpr",
+        elif name in ("jit", "closed_call", "custom_vjp_call_jaxpr",
                       "custom_jvp_call", "custom_vjp_call", "remat",
                       "checkpoint", "core_call"):
             sub = None

@@ -54,6 +54,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.extend import core as jex_core
 
 from repro.analysis import jaxpr_walk as jw
 from repro.analysis.report import Finding, PassResult
@@ -98,7 +99,7 @@ def _tainted_float_casts(jaxpr, taint_in, hits, depth=0):
 
     for eqn in jaxpr.eqns:
         in_taint = [(_var_key(v) in tainted) if hasattr(v, "aval") and
-                    not isinstance(v, jax.core.Literal) else False
+                    not isinstance(v, jex_core.Literal) else False
                     for v in eqn.invars]
         any_taint = any(in_taint)
         if (eqn.primitive.name == "convert_element_type" and in_taint[0]

@@ -94,9 +94,8 @@ class ServeCell:
     def __enter__(self) -> "ServeCell":
         assert self._stack is None, "cell already active"
         self._stack = contextlib.ExitStack()
-        self._stack.enter_context(self.mesh)
         self._stack.enter_context(
-            ctx.mesh_context(meshlib.dp_axes(self.mesh)))
+            ctx.mesh_context(self.mesh, meshlib.dp_axes(self.mesh)))
         return self
 
     def __exit__(self, *exc) -> None:
@@ -192,7 +191,7 @@ class StreamLanes:
             dstate, events = det.detector_step(
                 dstate, stream_engine.posteriors(logits), dcfg,
                 warm=stream_engine.warm(state))
-            return state, dstate, events
+            return state, dstate, {**events, "logits": logits}
 
         self._joint = None if pipelined else jax.jit(joint)
         if cell.flight is not None and cell.flight.stage_weights is None:
@@ -251,8 +250,9 @@ class StreamLanes:
         """Advance all lanes by ``chunk`` — raw audio
         [slots, chunk_samples], or pre-featurised frames
         [slots, chunk_hops, F] under ``feature_ingest``; returns
-        the detector events ``{"fired": [B], "score": [B], ...}`` (host
-        numpy — the per-hop sync point, as in the pre-cell server).
+        the detector events ``{"fired": [B], "score": [B], ...}`` and
+        the served ``"logits"`` [B, n_classes] (host numpy — the per-hop
+        sync point, as in the pre-cell server).
 
         ``ingest`` ([slots] ints) overrides the per-lane hop accounting
         for steps whose trailing chunk is zero-padded past a stream's
@@ -271,6 +271,7 @@ class StreamLanes:
             warm = self.state["embed"]["count"] >= \
                 stream_engine.window_frames(self.cell.engine.exec_cfg)
             self.dstate, events = self._det(self.dstate, logits, warm)
+            events = {**events, "logits": logits}
         events = jax.tree.map(np.asarray, jax.block_until_ready(events))
         dur_ms = 1e3 * (time.perf_counter() - t0)
         m.hop_ms.observe(dur_ms)

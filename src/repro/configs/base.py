@@ -75,8 +75,9 @@ class ModelConfig:
     # site outside repro/runtime should mutate them directly.
     softmax_mode: str = "exact"   # exact | lut | lut_fixed | pallas
     act_approx: str = "exact"     # exact | lut | pallas
-    kernel_interpret: bool = True  # pallas modes: interpret vs Mosaic,
-    #                                decided ONCE at plan time, not per call
+    kernel_interpret: bool = False  # pallas modes: interpret vs Mosaic,
+    #                                 pinned ONCE at plan time by
+    #                                 Backend.configure (plan_interpret)
     int_exec: bool = False        # integer-executing plan: linear layers
     #                               quantise activations (eq 9) and run the
     #                               stored int payload directly; pinned by

@@ -99,7 +99,7 @@ def softmax_exact(x: jnp.ndarray, axis: int = -1) -> jnp.ndarray:
     return jax.nn.softmax(x.astype(jnp.float32), axis=axis)
 
 
-def _pre_shift(num_q: jnp.ndarray, pre: int) -> jnp.ndarray:
+def pre_shift(num_q: jnp.ndarray, pre: int) -> jnp.ndarray:
     """Round-to-nearest right shift of the Q8.24 numerators.  Truncating
     here instead biases every lane low by ~2^{pre-1}, which deflates the
     row sum and turns into a +8% normalisation overshoot at K=32k."""
@@ -144,7 +144,7 @@ def softmax_lut(x: jnp.ndarray, axis: int = -1, *, fixed: bool = False,
     z_q = fxp.to_fixed(z)
     num_q = jnp.take(jnp.asarray(bank.exp_q24),
                      lutlib.exp_index_from_q24(z_q))             # in [0, 1]
-    s_q = jnp.sum(_pre_shift(num_q, pre), axis=axis, keepdims=True)  # Q8.(24-pre)
+    s_q = jnp.sum(pre_shift(num_q, pre), axis=axis, keepdims=True)  # Q8.(24-pre)
     inv_q = lutlib.reciprocal_q24(s_q, bank, range_reduce=range_reduce)
     inv_q = inv_q >> pre                                          # back to Q8.24
     out_q = fxp.fixed_mul(num_q, inv_q, nonneg=True)
@@ -152,7 +152,7 @@ def softmax_lut(x: jnp.ndarray, axis: int = -1, *, fixed: bool = False,
 
 
 def softmax(x: jnp.ndarray, axis: int = -1, mode: str = "exact",
-            interpret: bool = True, **kw) -> jnp.ndarray:
+            interpret: bool = False, **kw) -> jnp.ndarray:
     # quantisation-health tap (telemetry.taps): trace-time no-op unless an
     # Engine taps program is collecting.  Placed in the dispatcher — never
     # inside the ste() custom_vjp primal, whose inner trace's tracers must
@@ -175,7 +175,7 @@ def softmax(x: jnp.ndarray, axis: int = -1, mode: str = "exact",
 
 
 def masked_softmax(s: jnp.ndarray, mask: jnp.ndarray | None,
-                   mode: str = "exact", interpret: bool = True) -> jnp.ndarray:
+                   mode: str = "exact", interpret: bool = False) -> jnp.ndarray:
     """Softmax over the last axis with *structural* masking.
 
     For the LUT modes, masked lanes are excluded from the numerator sum
@@ -249,7 +249,7 @@ def masked_softmax(s: jnp.ndarray, mask: jnp.ndarray | None,
                              lutlib.exp_index_from_q24(z_q))
             if mk is not None:
                 num_q = jnp.where(mk, num_q, 0)
-            s_q = jnp.sum(_pre_shift(num_q, pre), axis=-1, keepdims=True)
+            s_q = jnp.sum(pre_shift(num_q, pre), axis=-1, keepdims=True)
             s_q = jnp.maximum(s_q, 1)
             inv_q = lutlib.reciprocal_q24(s_q, bank) >> pre
             return fxp.to_float(fxp.fixed_mul(num_q, inv_q, nonneg=True))
@@ -294,7 +294,7 @@ def gelu_lut(x: jnp.ndarray, *, interp: bool = False,
                      jnp.where(x < lutlib.GELU_LO, 0.0, mid))
 
 
-def gelu(x: jnp.ndarray, mode: str = "exact", interpret: bool = True,
+def gelu(x: jnp.ndarray, mode: str = "exact", interpret: bool = False,
          **kw) -> jnp.ndarray:
     if _health.active():   # health tap; see softmax() for placement notes
         _health.tap_gelu(x)
@@ -357,7 +357,7 @@ def sqrelu(x: jnp.ndarray) -> jnp.ndarray:
     return r * r
 
 
-def activation(name: str, mode: str = "exact", interpret: bool = True):
+def activation(name: str, mode: str = "exact", interpret: bool = False):
     """Resolve an activation by config name, honouring the approx mode.
     ``interpret`` only applies to the pallas kernel mode (pinned at plan
     time by repro.runtime); SiLU-family pallas requests fall back to the

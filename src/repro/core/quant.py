@@ -392,7 +392,13 @@ def int_exec_einsum(eq: str, x: jnp.ndarray, w: QTensor, *,
                                  x_exp=x_exp,
                                  residual_bits=residual_bits,
                                  interpret=interpret)
-        return out2.reshape(*lead, out2.shape[-1])
+        # the barrier stops XLA from folding this reshape into the
+        # consumers: LayerNorm then reduces the same [..., d] layout as
+        # the jnp plan's dot output, keeping the kernel plan's logits
+        # bit-identical to it (a flattened 2-D reduce sums in another
+        # order on the TPU)
+        return jax.lax.optimization_barrier(
+            out2.reshape(*lead, out2.shape[-1]))
     # contract the LAST activation axis in place — no flatten/unflatten
     # round-trip, so XLA keeps float-plan layouts downstream (a 2D
     # reshape here costs two copy fusions per linear and forces a

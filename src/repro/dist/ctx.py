@@ -5,8 +5,11 @@ calls the helpers below at every activation boundary and they resolve, at
 trace time, to either a no-op (single device, no mesh — the KWT/CPU test
 path) or a ``NamedSharding`` constraint on the ambient mesh when inside
 
-    with mesh, ctx.mesh_context(dp_axes, seq_axis=...):
+    with ctx.mesh_context(mesh, dp_axes, seq_axis=...):
         ...
+
+which makes ``mesh`` the ambient mesh (``jax.set_mesh``) and declares
+the axes; :func:`current_mesh` reads it back.
 
 Axis conventions (launch/mesh.py, DESIGN.md §3):
   'pod', 'data'  — data-parallel / FSDP axes (``dp_axes``),
@@ -27,7 +30,6 @@ import threading
 
 import jax
 import jax.numpy as jnp
-from jax.interpreters.pxla import thread_resources
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 TP = "model"   # tensor-parallel axis name (layers.TP; kept free of imports)
@@ -43,28 +45,31 @@ _STATE = _State()
 
 
 @contextlib.contextmanager
-def mesh_context(dp_axes, seq_axis=None):
-    """Declare the data-parallel axes (and optional Megatron-SP sequence
-    axis) that activation constraints shard over.  ``dp_axes`` may be
-    None/() for replicated-batch cells (e.g. long-context batch 1).
-    Contexts nest; the outer declaration is restored on exit."""
+def mesh_context(mesh, dp_axes, seq_axis=None):
+    """Enter ``mesh`` and declare the data-parallel axes (and optional
+    Megatron-SP sequence axis) that activation constraints shard over.
+    ``dp_axes`` may be None/() for replicated-batch cells (e.g.
+    long-context batch 1).  Contexts nest; the outer declaration is
+    restored on exit."""
     prev = (_STATE.active, _STATE.dp, _STATE.seq_axis)
     _STATE.active = True
     _STATE.dp = tuple(dp_axes) if dp_axes else None
     _STATE.seq_axis = seq_axis
     try:
-        yield
+        with jax.set_mesh(mesh):
+            yield
     finally:
         _STATE.active, _STATE.dp, _STATE.seq_axis = prev
 
 
-def _mesh():
-    return thread_resources.env.physical_mesh
+def current_mesh():
+    """The ambient mesh (empty outside :func:`mesh_context`)."""
+    return jax.sharding.get_abstract_mesh()
 
 
 def _mesh_active() -> bool:
     """True only under ``mesh_context`` AND a real (entered) device mesh."""
-    return _STATE.active and not _mesh().empty
+    return _STATE.active and not current_mesh().empty
 
 
 def dp_axes():
@@ -83,7 +88,7 @@ def _present(axis, mesh):
 
 
 def _constrain(x, dims):
-    mesh = _mesh()
+    mesh = current_mesh()
     spec = P(*(_present(d, mesh) for d in dims))
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 

@@ -54,7 +54,7 @@ def int8_matmul_raw(x_int: jnp.ndarray, w_int: jnp.ndarray, *, shift: int = 0,
                     out_int16: bool = False,
                     block_m: int = DEFAULT_BM, block_n: int = DEFAULT_BN,
                     block_k: int = DEFAULT_BK,
-                    interpret: bool = True) -> jnp.ndarray:
+                    interpret: bool) -> jnp.ndarray:
     """[M,K]i8 @ [K,N]i8 -> int32 (or int16) with epilogue shift ``>> shift``."""
     m, k = x_int.shape
     k2, n = w_int.shape

@@ -26,6 +26,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import lut as lutlib
+from repro.kernels.lut_lookup import lane_table, lookup
 
 DEFAULT_BQ = 128
 DEFAULT_BK = 128
@@ -36,7 +37,7 @@ def _lut_exp_f32(z, tab):
     """e^{-z} for z >= 0 via the 320-entry ROM (eq 11), f32 carry."""
     idx = jnp.clip((z * lutlib.BINS_PER_UNIT).astype(jnp.int32),
                    0, lutlib.N_EXP_ENTRIES - 1)
-    return jnp.take(tab, idx)
+    return lookup(tab, idx)
 
 
 def _attn_kernel(q_ref, k_ref, v_ref, tab_ref, o_ref, m_ref, l_ref, acc_ref,
@@ -99,7 +100,7 @@ def lut_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                   causal: bool = True, use_lut: bool = True,
                   scale: float | None = None,
                   block_q: int = DEFAULT_BQ, block_k: int = DEFAULT_BK,
-                  interpret: bool = True) -> jnp.ndarray:
+                  interpret: bool) -> jnp.ndarray:
     """Flash attention with LUT-exp online softmax.  GQA-aware."""
     b, hq, lq, d = q.shape
     _, hkv, lk, _ = k.shape
@@ -111,7 +112,7 @@ def lut_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     assert lq % bq == 0 and lk % bk == 0, (lq, lk, bq, bk)
     n_kv = lk // bk
     grid = (b, hq, lq // bq, n_kv)
-    bank = lutlib.make_lut_bank()
+    tab = lane_table(lutlib.make_lut_bank().exp_f32)
 
     kernel = functools.partial(
         _attn_kernel, scale=scale, causal=causal, n_kv=n_kv, bq=bq, bk=bk,
@@ -125,7 +126,7 @@ def lut_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                          lambda bb, h, i, kk, group=group: (bb, h // group, kk, 0)),
             pl.BlockSpec((1, 1, bk, d),
                          lambda bb, h, i, kk, group=group: (bb, h // group, kk, 0)),
-            pl.BlockSpec((lutlib.N_EXP_ENTRIES,), lambda bb, h, i, kk: (0,)),
+            pl.BlockSpec(tab.shape, lambda bb, h, i, kk: (0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, bq, d), lambda bb, h, i, kk: (bb, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
@@ -135,4 +136,4 @@ def lut_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
             pltpu.VMEM((bq, d), jnp.float32),
         ],
         interpret=interpret,
-    )(q, k, v, bank.exp_f32)
+    )(q, k, v, tab)

@@ -13,7 +13,9 @@ Float path (`fixed=False`): LUT_EXP gather in f32 + true division — the
 quantised-but-unaccelerated model (Table IX column 3).
 
 Tiling: one grid step owns a (block_m, N) row-slab so the row reduction
-stays on-chip; the 320-entry tables ride along as whole-array VMEM operands.
+stays on-chip; the 320-entry tables ride along as whole-array VMEM operands
+in the [3, 128] lane-segment layout Mosaic can gather from
+(``kernels.lut_lookup``).
 VMEM at N=32k, bm=8: 8*32768*4 = 1 MB in + 1 MB out (+LUTs) — fine.
 """
 
@@ -26,8 +28,10 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from repro.core import approx
 from repro.core import fixedpoint as fxp
 from repro.core import lut as lutlib
+from repro.kernels.lut_lookup import lane_table, lookup
 
 DEFAULT_BLOCK_M = 8
 
@@ -40,7 +44,7 @@ def _reciprocal_q24_body(s_q, inv_tab):
     m = ((s_q >> tp) << tn).astype(jnp.int32)
     shift = fxp.FRAC_BITS - int(np.log2(lutlib.BINS_PER_UNIT))
     idx = jnp.clip((m >> shift) - 1, 0, lutlib.N_EXP_ENTRIES - 1)
-    inv_m = jnp.take(inv_tab, idx)
+    inv_m = lookup(inv_tab, idx)
     limit = jnp.int32(2**31 - 1) >> tn
     return jnp.where(t >= 0, inv_m >> tp,
                      jnp.where(inv_m > limit, jnp.int32(2**31 - 1),
@@ -55,8 +59,8 @@ def _softmax_kernel_fixed(x_ref, exp_tab_ref, inv_tab_ref, o_ref, *, pre: int):
     z_q = jnp.round(z * float(fxp.ONE)).astype(jnp.int32)        # ALU_TO_FIXED
     shift = fxp.FRAC_BITS - int(np.log2(lutlib.BINS_PER_UNIT))
     idx = jnp.clip(z_q >> shift, 0, lutlib.N_EXP_ENTRIES - 1)
-    num_q = jnp.take(exp_tab, idx)                               # ALU_EXP
-    s_q = jnp.sum(num_q >> pre, axis=-1, keepdims=True)
+    num_q = lookup(exp_tab, idx)                                 # ALU_EXP
+    s_q = jnp.sum(approx.pre_shift(num_q, pre), axis=-1, keepdims=True)
     inv_q = _reciprocal_q24_body(s_q, inv_tab) >> pre            # ALU_INVERT
     out_q = fxp.fixed_mul(num_q, inv_q, nonneg=True)
     o_ref[...] = fxp.to_float(out_q).astype(o_ref.dtype)        # ALU_TO_FLOAT
@@ -68,14 +72,14 @@ def _softmax_kernel_float(x_ref, exp_tab_ref, o_ref):
     z = jnp.clip(jnp.max(x, axis=-1, keepdims=True) - x, 0.0, lutlib.EXP_RANGE)
     idx = jnp.clip((z * lutlib.BINS_PER_UNIT).astype(jnp.int32),
                    0, lutlib.N_EXP_ENTRIES - 1)
-    num = jnp.take(exp_tab, idx)
+    num = lookup(exp_tab, idx)
     o_ref[...] = (num / jnp.sum(num, axis=-1, keepdims=True)).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("fixed", "block_m", "interpret"))
 def lut_softmax_2d(x: jnp.ndarray, *, fixed: bool = True,
                    block_m: int = DEFAULT_BLOCK_M,
-                   interpret: bool = True) -> jnp.ndarray:
+                   interpret: bool) -> jnp.ndarray:
     """LUT softmax along the last axis of a [M, N] array."""
     m, n = x.shape
     bm = min(block_m, m)
@@ -84,7 +88,8 @@ def lut_softmax_2d(x: jnp.ndarray, *, fixed: bool = True,
     pre = max(0, int(np.ceil(np.log2(max(n, 1)))) - 6)
     grid = (m // bm,)
     row_spec = pl.BlockSpec((bm, n), lambda i: (i, 0))
-    tab_spec = pl.BlockSpec((lutlib.N_EXP_ENTRIES,), lambda i: (0,))
+    exp_tab, inv_tab = lane_table(bank.exp_q24), lane_table(bank.inv_q24)
+    tab_spec = pl.BlockSpec(exp_tab.shape, lambda i: (0, 0))
     if fixed:
         return pl.pallas_call(
             functools.partial(_softmax_kernel_fixed, pre=pre),
@@ -93,7 +98,7 @@ def lut_softmax_2d(x: jnp.ndarray, *, fixed: bool = True,
             out_specs=row_spec,
             out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
             interpret=interpret,
-        )(x, bank.exp_q24, bank.inv_q24)
+        )(x, exp_tab, inv_tab)
     return pl.pallas_call(
         _softmax_kernel_float,
         grid=grid,
@@ -101,4 +106,4 @@ def lut_softmax_2d(x: jnp.ndarray, *, fixed: bool = True,
         out_specs=row_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         interpret=interpret,
-    )(x, bank.exp_f32)
+    )(x, lane_table(bank.exp_f32))

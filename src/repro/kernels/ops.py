@@ -1,10 +1,10 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-Handles: arbitrary leading batch dims, padding to block multiples, dtype
-plumbing, and interpret-mode selection.  ``repro.runtime`` decides
-interpret-vs-Mosaic ONCE at plan time and passes the literal value down;
-the ``interpret=None`` auto-probe remains only for direct/ad-hoc callers
-(tests, notebooks) that bypass the runtime.
+Handles: arbitrary leading batch dims, padding to block multiples and
+dtype plumbing.  Every wrapper takes ``interpret`` explicitly: the
+runtime passes its plan-time decision (``runtime.plan_interpret``), and
+nothing here probes the backend, so a caller on the chip never lands in
+the interpreter by default.
 
 All block geometry goes through two shared helpers:
 
@@ -21,19 +21,12 @@ of the previously duplicated pad/shrink loops.
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from repro.kernels import int8_matmul as _mm
 from repro.kernels import lut_attention as _attn
 from repro.kernels import lut_gelu as _gelu
 from repro.kernels import lut_softmax as _sm
-
-
-def _auto_interpret(interpret: bool | None) -> bool:
-    if interpret is not None:
-        return interpret
-    return jax.default_backend() != "tpu"
 
 
 def pad_to_block(x: jnp.ndarray, axis: int, mult: int, value=0.0):
@@ -75,7 +68,7 @@ def _softmax_block_m(m: int, n: int) -> int:
 
 
 def lut_gelu(x: jnp.ndarray, *, interp: bool = False,
-             interpret: bool | None = None) -> jnp.ndarray:
+             interpret: bool) -> jnp.ndarray:
     """Piecewise LUT GELU over any-shaped input."""
     shape = x.shape
     flat = x.reshape(-1, shape[-1]) if x.ndim > 1 else x.reshape(1, -1)
@@ -84,12 +77,12 @@ def lut_gelu(x: jnp.ndarray, *, interp: bool = False,
     bm = fit_block(padded.shape[0], _gelu.DEFAULT_BLOCK_M)
     bn = fit_block(padded.shape[1], _gelu.DEFAULT_BLOCK_N)
     out = _gelu.lut_gelu_2d(padded, interp=interp, block_m=bm, block_n=bn,
-                            interpret=_auto_interpret(interpret))
+                            interpret=interpret)
     return out[:m0, :n0].reshape(shape)
 
 
 def lut_softmax(x: jnp.ndarray, *, fixed: bool = True,
-                interpret: bool | None = None) -> jnp.ndarray:
+                interpret: bool) -> jnp.ndarray:
     """LUT softmax along the last axis of any-shaped input.
 
     Padding rows (axis 0) are whole extra rows and are sliced away before
@@ -101,14 +94,14 @@ def lut_softmax(x: jnp.ndarray, *, fixed: bool = True,
     padded, m0 = pad_to_block(flat, 0, 8)
     bm = _softmax_block_m(padded.shape[0], padded.shape[1])
     out = _sm.lut_softmax_2d(padded, fixed=fixed, block_m=bm,
-                             interpret=_auto_interpret(interpret))
+                             interpret=interpret)
     return out[:m0].reshape(shape)
 
 
 def int8_matmul(x_int, w_int, *, x_exp: int | None = None,
                 w_exp: int | None = None, out_exp: int | None = None,
                 residual_bits: int = 32,
-                interpret: bool | None = None) -> jnp.ndarray:
+                interpret: bool) -> jnp.ndarray:
     """Quantised matmul -> dequantised f32 (contract matches ref.int8_matmul).
 
     Operands may be raw int arrays (+ explicit exponents) or stored
@@ -137,16 +130,18 @@ def int8_matmul(x_int, w_int, *, x_exp: int | None = None,
         "raw int operands need explicit x_exp/w_exp"
     m, k = x_int.shape
     k2, n = w_int.shape
-    xp, _ = pad_to_block(x_int, 0, 8)
+    # int8 packs (32, 128) per vreg: the row block is a multiple of 32
+    # (or the whole padded row axis), and rows pad up to that block
+    bm = min(_mm.DEFAULT_BM, -(-m // 32) * 32)
+    xp, _ = pad_to_block(x_int, 0, bm)
     xp, _ = pad_to_block(xp, 1, 128)
     wp, _ = pad_to_block(w_int, 0, 128)
     wp, _ = pad_to_block(wp, 1, 128)
     acc_exp = x_exp + w_exp
     out_exp = acc_exp if out_exp is None else out_exp
-    bm = fit_block(xp.shape[0], _mm.DEFAULT_BM)
     out = _mm.int8_matmul_raw(
         xp, wp, shift=acc_exp - out_exp, out_int16=(residual_bits == 16),
-        block_m=bm, interpret=_auto_interpret(interpret))
+        block_m=bm, interpret=interpret)
     out = out[:m, :n].astype(jnp.float32) * (2.0 ** (-out_exp))
     if w_axis is not None:
         out = out * jnp.exp2(-w_axis.astype(jnp.float32))
@@ -156,7 +151,7 @@ def int8_matmul(x_int, w_int, *, x_exp: int | None = None,
 def lut_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                   causal: bool = True, use_lut: bool = True,
                   scale: float | None = None,
-                  interpret: bool | None = None) -> jnp.ndarray:
+                  interpret: bool) -> jnp.ndarray:
     """Flash attention with LUT-exp softmax; [B,H,L,D] GQA layout."""
     lq, lk = q.shape[2], k.shape[2]
     block_q = fit_block(lq, _attn.DEFAULT_BQ)
@@ -164,4 +159,4 @@ def lut_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     return _attn.lut_attention(
         q, k, v, causal=causal, use_lut=use_lut, scale=scale,
         block_q=block_q, block_k=block_k,
-        interpret=_auto_interpret(interpret))
+        interpret=interpret)

@@ -52,6 +52,7 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     serve_common.add_telemetry_args(ap)
     args = ap.parse_args(argv)
+    runtime.enable_compile_cache()
 
     entry = registry.get(args.arch)
     cfg = entry.smoke if args.smoke else entry.config
@@ -69,7 +70,8 @@ def main(argv=None):
     with serve_common.session(args.telemetry_out) as (tracer, met):
         params = mod.init_params(cfg, jax.random.PRNGKey(args.seed))
         eng = runtime.compile_model(cfg, params, backend=args.backend)
-        telemetry.log("engine", plan=eng.describe())
+        telemetry.log("engine", plan=eng.describe(),
+                      device=jax.devices()[0].device_kind)
         cell = cellmod.ServeCell(eng, slots=args.slots, registry=met)
         with cell:
             sched = cell.lm_scheduler(max_len=args.max_len,
@@ -84,7 +86,7 @@ def main(argv=None):
                       wall_s=dt, tok_s=decoded / dt,
                       backend=eng.backend_name,
                       **met.histogram("cell_decode_latency_ms").summary())
-    return out
+    return requests, out
 
 
 if __name__ == "__main__":

@@ -328,7 +328,7 @@ def lower_program(prog: Program, mesh, seq_axis=None):
     from repro.dist import ctx
     seq_axis = seq_axis or prog.seq_axis
     dp = prog.dp if prog.dp != "auto" else meshlib.dp_axes(mesh)
-    with mesh, ctx.mesh_context(dp, seq_axis):
+    with ctx.mesh_context(mesh, dp, seq_axis):
         jitted = jax.jit(prog.fn, in_shardings=prog.shardings,
                          donate_argnums=prog.donate)
         return jitted.lower(*prog.args)

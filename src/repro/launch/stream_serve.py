@@ -24,9 +24,13 @@ trading detection latency for throughput — and only then rejects.
 ``--watch-dir`` points the cell at a checkpoint directory for in-flight
 hot-swap of freshly published artifacts.
 
+The model is the registry entry's published config (``kwt-tiny`` is the
+paper's whole model; ``kwt-1`` has 12 layers); ``--smoke`` serves its
+CPU-sized cut instead.
+
 Usage (CPU, reduced):
-  PYTHONPATH=src python -m repro.launch.stream_serve --streams 8 --slots 4 \
-      --hops 120 [--backend lut] [--train-steps 80]
+  PYTHONPATH=src python -m repro.launch.stream_serve --smoke --streams 8 \
+      --slots 4 --hops 120 [--backend lut] [--train-steps 80]
 """
 
 from __future__ import annotations
@@ -84,6 +88,8 @@ def train_params(cfg, fcfg, n_steps: int, seed: int):
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="kwt-tiny")
+    ap.add_argument("--smoke", action="store_true",
+                    help="serve the registry's CPU-sized cut of --arch")
     ap.add_argument("--streams", type=int, default=8,
                     help="total streams to serve")
     ap.add_argument("--slots", type=int, default=4, help="batch lanes")
@@ -113,9 +119,10 @@ def main(argv=None):
     serve_common.add_telemetry_args(ap)
     args = ap.parse_args(argv)
     backend = args.backend
+    runtime.enable_compile_cache()
 
     entry = registry.get(args.arch)
-    base_cfg = entry.smoke
+    base_cfg = entry.smoke if args.smoke else entry.config
     assert base_cfg.family == "kwt", "streaming serve drives the KWT family"
     fcfg = features.FrontendConfig()
     dcfg = det.DetectorConfig()
@@ -124,7 +131,8 @@ def main(argv=None):
     # selection for serving.
     fparams = train_params(base_cfg, fcfg, args.train_steps, args.seed)
     eng = runtime.compile_model(base_cfg, fparams, backend=backend)
-    telemetry.log("engine", plan=eng.describe())
+    telemetry.log("engine", plan=eng.describe(),
+                  device=jax.devices()[0].device_kind)
 
     B, k = args.slots, args.chunk_hops
     queue = list(range(args.streams))
@@ -154,12 +162,12 @@ def main(argv=None):
             watch_like=eng.params if args.watch_dir else None,
             probe=probe if args.watch_dir else None)
         with cell:
-            fired = _serve(cell, sources, queue, fcfg, dcfg, k, met)
-    return fired
+            return _serve(cell, sources, queue, fcfg, dcfg, k, met)
 
 
 def _serve(cell, sources, queue, fcfg, dcfg, chunk_hops, met):
-    """The serve loop proper: offer -> join -> hop -> evict, to drain."""
+    """The serve loop proper: offer -> join -> hop -> evict, to drain.
+    Returns the ``serve_done`` summary plus the ``fired`` detections."""
     lanes = cell.stream_lanes(fcfg, dcfg, chunk_hops=chunk_hops)
     B = cell.slots
     shed = []
@@ -234,14 +242,15 @@ def _serve(cell, sources, queue, fcfg, dcfg, chunk_hops, met):
     served = [s for sid, s in sources.items() if sid in done]
     audio_s = sum(s["hops"] for s in served) * fcfg.hop_len / fcfg.sample_rate
     truth = sum(len(s["events"]) for s in served)
-    telemetry.log("serve_done", streams=n_to_serve, shed=len(shed),
-                  audio_s=audio_s, wall_s=dt, realtime_x=audio_s / dt,
-                  fired=len(fired), keywords=truth,
-                  ingested_hops=int(met.counter("cell_hops_total").value),
-                  offered_hops=sum(s["hops"] for s in served),
-                  backend=eng.backend_name,
-                  **met.histogram("cell_hop_latency_ms").summary())
-    return fired
+    summary = dict(streams=n_to_serve, served=len(done), shed=len(shed),
+                   audio_s=audio_s, wall_s=dt, realtime_x=audio_s / dt,
+                   fired=len(fired), keywords=truth,
+                   ingested_hops=int(met.counter("cell_hops_total").value),
+                   offered_hops=sum(s["hops"] for s in served),
+                   backend=eng.backend_name,
+                   **met.histogram("cell_hop_latency_ms").summary())
+    telemetry.log("serve_done", **summary)
+    return {**summary, "fired": fired}
 
 
 if __name__ == "__main__":

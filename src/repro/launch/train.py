@@ -102,6 +102,8 @@ def main(argv=None):
     ap.add_argument("--distill-temp", type=float, default=2.0)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    from repro.runtime import enable_compile_cache
+    enable_compile_cache()
 
     entry = registry.get(args.arch)
     cfg = entry.smoke if args.smoke else entry.config
@@ -154,7 +156,7 @@ def main(argv=None):
                         is_leaf=lambda x: isinstance(
                             x, jax.sharding.PartitionSpec))
 
-    with mesh, ctx.mesh_context(dp):
+    with ctx.mesh_context(mesh, dp):
         params = jax.jit(
             lambda k: mod.init_params(cfg, k),
             out_shardings=p_sh)(jax.random.PRNGKey(args.seed))

@@ -126,8 +126,7 @@ def apply_moe(p, x, cfg):
             xt, gates, idx, p["w_gate"], p["w_up"], p["w_down"], cfg,
             e_lo=0, e_n=Ep, C=_capacity(T, cfg))
     else:
-        from jax.interpreters.pxla import thread_resources
-        mesh = thread_resources.env.physical_mesh
+        mesh = ctx.current_mesh()
         dp = ctx.dp_axes()
         tp = mesh.shape["model"]
         dp_total = 1

@@ -46,7 +46,7 @@ from repro.analysis import jaxpr_walk as jw
 # op class by trace-time frame function name (innermost frame wins)
 _OP_BY_FUNC = {
     "softmax": ("softmax_exact", "softmax_lut", "fixed_softmax",
-                "masked_softmax", "softmax", "_pre_shift", "lut_softmax",
+                "masked_softmax", "softmax", "pre_shift", "lut_softmax",
                 "_softmax_kernel"),
     "gelu": ("gelu_exact", "gelu_lut", "gelu", "lut_gelu", "silu",
              "sigmoid_lut", "softplus", "sqrelu", "_gelu_kernel",

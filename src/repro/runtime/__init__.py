@@ -10,6 +10,7 @@ directly — see README §repro.runtime for the migration table.
 
 from repro.runtime.backends import (Backend, available_backends, get_backend,
                                     plan_interpret, register_backend)
+from repro.runtime.compile_cache import compile_cache_dir, enable_compile_cache
 from repro.runtime.engine import Engine, EngineHandle, compile_model
 from repro.runtime.recipe import QuantRecipe
 
@@ -22,5 +23,6 @@ def quantize_params(params, cfg, rounding: str = "nearest"):
 
 
 __all__ = ["Backend", "Engine", "EngineHandle", "QuantRecipe",
-           "available_backends", "compile_model", "get_backend",
-           "plan_interpret", "quantize_params", "register_backend"]
+           "available_backends", "compile_cache_dir", "compile_model",
+           "enable_compile_cache", "get_backend", "plan_interpret",
+           "quantize_params", "register_backend"]

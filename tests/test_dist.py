@@ -42,7 +42,7 @@ def test_sharded_dense_matches_local():
         ref, gref = jax.value_and_grad(T.loss_fn)(params, batch, cfg)
         mesh = jax.make_mesh((4, 2), ('data', 'model'),
                              axis_types=(jax.sharding.AxisType.Auto,)*2)
-        with mesh, ctx.mesh_context(('data',)):
+        with ctx.mesh_context(mesh, ('data',)):
             p_sh = jax.tree.map(lambda s: NamedSharding(mesh, s), T.param_specs(cfg),
                                 is_leaf=lambda x: isinstance(x, P))
             ps = jax.device_put(params, p_sh)
@@ -72,7 +72,7 @@ def test_sharded_moe_matches_local_dropfree():
         ref = T.loss_fn(params, batch, cfg)
         mesh = jax.make_mesh((4, 2), ('data', 'model'),
                              axis_types=(jax.sharding.AxisType.Auto,)*2)
-        with mesh, ctx.mesh_context(('data',)):
+        with ctx.mesh_context(mesh, ('data',)):
             p_sh = jax.tree.map(lambda s: NamedSharding(mesh, s), T.param_specs(cfg),
                                 is_leaf=lambda x: isinstance(x, P))
             ps = jax.device_put(params, p_sh)

@@ -16,7 +16,7 @@ KEY = jax.random.PRNGKey(42)
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_lut_gelu_sweep(shape, dtype):
     x = (jax.random.normal(KEY, shape) * 3).astype(dtype)
-    got = ops.lut_gelu(x)
+    got = ops.lut_gelu(x, interpret=True)
     want = ref.lut_gelu(x)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32),
@@ -27,7 +27,7 @@ def test_lut_gelu_sweep(shape, dtype):
 @pytest.mark.parametrize("fixed", [True, False])
 def test_lut_softmax_sweep(shape, fixed):
     x = jax.random.normal(KEY, shape) * 4
-    got = ops.lut_softmax(x, fixed=fixed)
+    got = ops.lut_softmax(x, fixed=fixed, interpret=True)
     want = ref.lut_softmax(x, fixed=fixed)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-6)
     # sanity vs exact softmax
@@ -38,9 +38,19 @@ def test_lut_softmax_fixed_bit_exact_paper_scale():
     """At the paper's SEQLEN=27 the kernel must match the Q8.24 reference
     bit-for-bit (same LUT indices, same fixed multiply)."""
     x = jax.random.normal(KEY, (16, 27)) * 3
-    got = ops.lut_softmax(x, fixed=True)
+    got = ops.lut_softmax(x, fixed=True, interpret=True)
     want = ref.lut_softmax(x, fixed=True)
     assert jnp.array_equal(got, want)
+
+
+@pytest.mark.parametrize("shape", [(8, 99), (8, 320), (3, 1000)])
+def test_lut_softmax_fixed_bit_exact_long_rows(shape):
+    """Past 64 keys the row sum is pre-shifted to stay in int32; the
+    kernel rounds that shift like the Q8.24 reference, so longer rows
+    (KWT-1's 99, LM caches) stay bit-exact too."""
+    x = jax.random.normal(jax.random.fold_in(KEY, shape[1]), shape) * 3
+    got = ops.lut_softmax(x, fixed=True, interpret=True)
+    assert jnp.array_equal(got, ref.lut_softmax(x, fixed=True))
 
 
 @pytest.mark.parametrize("mnk", [(8, 16, 32), (50, 70, 200), (128, 128, 128),
@@ -53,7 +63,7 @@ def test_int8_matmul_sweep(mnk, residual_bits):
     x = jax.random.randint(k1, (m, k), -16, 16, jnp.int8)
     w = jax.random.randint(k2, (k, n), -16, 16, jnp.int8)
     got = ops.int8_matmul(x, w, x_exp=5, w_exp=6, out_exp=7,
-                          residual_bits=residual_bits)
+                          residual_bits=residual_bits, interpret=True)
     want = ref.int8_matmul(x, w, x_exp=5, w_exp=6, out_exp=7,
                            residual_bits=residual_bits)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-6)
@@ -71,14 +81,14 @@ def test_int8_matmul_accepts_stored_qtensors():
     qx = quant.QTensor(x, 5)
     qw = quant.QTensor.store(w4, 6, bits=4)           # nibble-packed
     assert qw.packed and qw.values.dtype == jnp.uint8
-    got = ops.int8_matmul(qx, qw, out_exp=7)
+    got = ops.int8_matmul(qx, qw, out_exp=7, interpret=True)
     want = ref.int8_matmul(x, w4, x_exp=5, w_exp=6, out_exp=7)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-6)
     # per-channel axis exponents fold into the epilogue
     axis = jax.random.randint(jax.random.fold_in(KEY, 3), (16,),
                               -2, 3).astype(jnp.int8)
     qwc = quant.QTensor.store(w4, 6, bits=4, axis_exponents=axis)
-    got_c = ops.int8_matmul(qx, qwc, out_exp=7)
+    got_c = ops.int8_matmul(qx, qwc, out_exp=7, interpret=True)
     np.testing.assert_allclose(
         np.asarray(got_c),
         np.asarray(want * np.exp2(-np.asarray(axis, np.float32))),
@@ -121,11 +131,13 @@ def test_lut_attention_sweep(b, hq, hkv, lq, lk, d, causal):
     q = jax.random.normal(ks[0], (b, hq, lq, d), jnp.float32)
     k = jax.random.normal(ks[1], (b, hkv, lk, d), jnp.float32)
     v = jax.random.normal(ks[2], (b, hkv, lk, d), jnp.float32)
-    exact = ops.lut_attention(q, k, v, causal=causal, use_lut=False)
+    exact = ops.lut_attention(q, k, v, causal=causal, use_lut=False,
+                              interpret=True)
     r_exact = ref.lut_attention(q, k, v, causal=causal, softmax_mode="exact")
     np.testing.assert_allclose(np.asarray(exact), np.asarray(r_exact),
                                rtol=2e-5, atol=2e-5)
-    lut = ops.lut_attention(q, k, v, causal=causal, use_lut=True)
+    lut = ops.lut_attention(q, k, v, causal=causal, use_lut=True,
+                            interpret=True)
     r_lut = ref.lut_attention(q, k, v, causal=causal, softmax_mode="lut")
     # multi-tile online-LUT telescopes differently from single-shot LUT:
     # bounded by the LUT bin width (1/32) relative error per factor.
@@ -140,6 +152,7 @@ def test_lut_attention_bf16():
     q = jax.random.normal(ks[0], (1, 2, 32, 32), jnp.bfloat16)
     k = jax.random.normal(ks[1], (1, 2, 32, 32), jnp.bfloat16)
     v = jax.random.normal(ks[2], (1, 2, 32, 32), jnp.bfloat16)
-    out = ops.lut_attention(q, k, v, causal=True, use_lut=True)
+    out = ops.lut_attention(q, k, v, causal=True, use_lut=True,
+                            interpret=True)
     assert out.dtype == jnp.bfloat16
     assert bool(jnp.all(jnp.isfinite(out.astype(jnp.float32))))

@@ -46,6 +46,14 @@ def engines(params):
             for b in ("float", "lut_float", "lut", "pallas")}
 
 
+@pytest.fixture(scope="module")
+def resident(params):
+    """The integer-resident lut plan that still dequantises per call:
+    the one plan flavour with an unpack stage (int-exec plans have none)."""
+    return runtime.compile_model(CFG, params, backend="lut",
+                                 integer_exec=False)
+
+
 # ---------------------------------------------------------------------------
 # cost model: hand-counted ground truth
 # ---------------------------------------------------------------------------
@@ -88,13 +96,14 @@ def test_matmul_flops_invariant_across_backends(engines):
     assert len(set(counts.values())) == 1, counts
 
 
-def test_unpack_stage_only_for_int_resident(engines):
+def test_unpack_stage_only_for_int_resident(engines, resident):
     stages_f = perf.engine_cost(engines["float"]).by_stage()
-    stages_q = perf.engine_cost(engines["lut"]).by_stage()
+    stages_q = perf.engine_cost(resident).by_stage()
     assert "unpack" not in stages_f
+    assert "unpack" not in perf.engine_cost(engines["lut"]).by_stage()
     assert stages_q["unpack"].flops > 0
     # unpack work scales with params, not batch
-    stages_q8 = perf.engine_cost(engines["lut"], batch=8).by_stage()
+    stages_q8 = perf.engine_cost(resident, batch=8).by_stage()
     assert stages_q8["unpack"].flops == stages_q["unpack"].flops
 
 
@@ -111,8 +120,8 @@ def test_stage_split_matches_span_names(engines):
     assert "featurise" not in hop_f.by_stage()
 
 
-def test_softmax_gelu_rows_and_report_shape(engines):
-    rep = perf.engine_cost(engines["lut"], batch=1)
+def test_softmax_gelu_rows_and_report_shape(resident):
+    rep = perf.engine_cost(resident, batch=1)
     ops = {op for (_, op) in rep.lines}
     assert {"softmax", "gelu", "matmul", "norm"} <= ops
     rows = rep.rows(perf.PAPER_MCU)
@@ -350,8 +359,8 @@ def test_flight_dump_artifact_schema(flight):
 # integration: Engine.describe(cost=True), empty latency_summary
 # ---------------------------------------------------------------------------
 
-def test_describe_cost_appends_table(engines):
-    out = engines["lut"].describe(cost=True)
+def test_describe_cost_appends_table(resident):
+    out = resident.describe(cost=True)
     assert "cost/fwd" in out and "est_cycles" in out
     assert "| unpack |" in out                 # the paper-style table
 

@@ -8,6 +8,8 @@ to the jnp Q8.24 LUT reference on KWT (mask-free attention takes the raw
 kernel path).
 """
 
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -474,3 +476,71 @@ def test_pad_to_block_pads_and_reports_size():
     assert float(p2[:, 27:].max()) == -1.0
     same, s0 = ops.pad_to_block(x, 0, 5)
     assert same is x and s0 == 5                  # no-op when aligned
+
+
+# ---------------------------------------------------------------------------
+# the plain float32 reference, and no path that hides the device
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("name", ["kwt-tiny", "kwt-1"])
+def test_float_engine_matches_plain_reference(name):
+    """The float plan against models.reference, which shares no layer
+    code with it: the published widths (KWT-1 at all 12 layers)."""
+    from repro.models import reference
+    cfg = registry.get(name).config
+    p = kwt.init_params(cfg, jax.random.PRNGKey(4))
+    x = jax.random.normal(jax.random.PRNGKey(5), (4, *cfg.input_dim))
+    with jax.default_matmul_precision("highest"):
+        ref = jax.jit(lambda pp, m: reference.kwt_forward(pp, m, cfg))(p, x)
+    got = runtime.compile_model(cfg, p, backend="float").forward(x)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                               rtol=0, atol=1e-4)
+
+
+def test_chip_smoke_refuses_cpu():
+    """Without a TPU the chip smoke exits nonzero, names the platform it
+    found, and prints no result (JAX held to the CPU: the TPU library is
+    never initialised)."""
+    import subprocess
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
+    out = subprocess.run([sys.executable, os.path.join(root, "chip_smoke.py")],
+                         capture_output=True, text=True, env=env, timeout=300)
+    assert out.returncode != 0
+    assert "'cpu'" in out.stderr
+    assert '"ok"' not in out.stdout
+
+
+def test_compile_cache_dir_is_env_or_fixed_checkout_path(monkeypatch):
+    from repro.runtime import compile_cache
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+    assert runtime.compile_cache_dir() == "/elsewhere/cache"
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    fixed = runtime.compile_cache_dir()
+    assert fixed == runtime.compile_cache_dir() == \
+        str(compile_cache.CHECKOUT_CACHE)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert fixed == os.path.join(root, ".jax_cache")
+
+
+def test_enable_compile_cache_honours_env_dir(tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set, the helper leaves JAX's own
+    reading of it alone: compiled programs land there."""
+    import subprocess
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    cache = tmp_path / "cache"
+    env = {**os.environ, "JAX_PLATFORMS": "cpu",
+           "PYTHONPATH": os.path.join(root, "src"),
+           "JAX_COMPILATION_CACHE_DIR": str(cache),
+           "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "0"}
+    code = ("import jax, jax.numpy as jnp\n"
+            "from repro.runtime import enable_compile_cache\n"
+            "print(enable_compile_cache())\n"
+            "jax.jit(lambda x: x * 3)(jnp.ones(8)).block_until_ready()\n")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, env=env, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.strip() == str(cache)
+    assert any(cache.iterdir())
