@@ -1,0 +1,152 @@
+"""Reduce a profiler trace (``.xplane.pb``) to the numbers the per-layer
+metrics read.
+
+* The window is the harness's ``bench_window`` span on the host.
+* Busy time is the union of the intervals in which an operation ran on a
+  device (the device planes' ``XLA Ops`` line), clipped to the window and
+  averaged over the devices that ran any.
+* Scope time is the device time of the operations whose op name path
+  (the trace's ``tf_op``, read by :mod:`yardstick.xplane`) carries a stage
+  of the stream engine (``featurise``, ``embed``, ``encode``: the
+  program's ``jax.named_scope`` names); the rest, such as the copies of
+  lane state and ops that carry no name, counts as ``other``.
+* Each idle gap of the device is labelled by the harness span
+  (``prepare_chunk``, ``hop``) the host was in at its middle.
+"""
+
+from __future__ import annotations
+
+import collections
+import dataclasses
+import glob
+import os
+
+import numpy as np
+
+SCOPES = ("featurise", "embed", "encode")
+HOST_SPANS = ("prepare_chunk", "hop")
+WINDOW_SPAN = "bench_window"
+OPS_LINE = "XLA Ops"
+
+
+@dataclasses.dataclass
+class Reduction:
+    window_s: float
+    busy_s: float
+    scope_s: dict
+    ops: dict                 # op name -> device seconds
+    gaps: dict                # host span -> idle device seconds
+    devices: int
+
+    def top_ops(self, n: int) -> list:
+        return [[k, float(v)] for k, v in
+                sorted(self.ops.items(), key=lambda kv: -kv[1])[:n]]
+
+    def idle_by_span(self, n: int) -> list:
+        return [[k, float(v)] for k, v in
+                sorted(self.gaps.items(), key=lambda kv: -kv[1])[:n]]
+
+
+def scope_of(op_path: str) -> str:
+    """The stream-engine stage an op's name path names, or ``other``."""
+    parts = op_path.split("/")
+    for s in SCOPES:
+        if s in parts:
+            return s
+    return "other"
+
+
+def short_name(hlo_text: str) -> str:
+    """``fusion.12`` of ``%fusion.12 = f32[...] fusion(...)``, with the
+    instance number dropped so repeated ops sum: ``fusion``."""
+    name = hlo_text.split(" = ", 1)[0].lstrip("%")
+    base, _, num = name.rpartition(".")
+    return base if base and num.isdigit() else name
+
+
+def _union(intervals: np.ndarray) -> np.ndarray:
+    """Merged [start, end) intervals, sorted."""
+    if len(intervals) == 0:
+        return intervals.reshape(0, 2)
+    iv = intervals[np.argsort(intervals[:, 0])]
+    out = [list(iv[0])]
+    for s, e in iv[1:]:
+        if s <= out[-1][1]:
+            out[-1][1] = max(out[-1][1], e)
+        else:
+            out.append([s, e])
+    return np.asarray(out)
+
+
+def reduce_file(path: str) -> Reduction:
+    from jax.profiler import ProfileData
+
+    from yardstick import xplane
+    pd = ProfileData.from_file(path)
+    op_path = xplane.op_names(path)
+    window, spans = None, []
+    device_ops = []
+    for plane in pd.planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name == WINDOW_SPAN:
+                        window = (ev.start_ns, ev.start_ns + ev.duration_ns)
+                    elif ev.name in HOST_SPANS:
+                        spans.append((ev.start_ns, ev.start_ns
+                                      + ev.duration_ns, ev.name))
+        elif plane.name.startswith("/device:"):
+            evs = []
+            for line in plane.lines:
+                if line.name != OPS_LINE:
+                    continue
+                for ev in line.events:
+                    evs.append((ev.start_ns, ev.start_ns + ev.duration_ns,
+                                ev.name, op_path.get(ev.name, "")))
+            if evs:
+                device_ops.append(evs)
+    if window is None:
+        raise ValueError(f"{path}: no {WINDOW_SPAN!r} span in the trace")
+    w0, w1 = window
+    spans.sort()
+    span_starts = np.asarray([s[0] for s in spans], np.float64)
+    busy, scope_s = 0.0, collections.Counter()
+    ops, gaps = collections.Counter(), collections.Counter()
+    for evs in device_ops:
+        iv = []
+        for s, e, name, path in evs:
+            s, e = max(s, w0), min(e, w1)
+            if e <= s:
+                continue
+            iv.append((s, e))
+            sec = (e - s) * 1e-9
+            scope = scope_of(path)
+            scope_s[scope] += sec
+            ops[f"{scope}/{short_name(name)}"] += sec
+        merged = _union(np.asarray(iv, np.float64))
+        busy += float(np.sum(merged[:, 1] - merged[:, 0])) * 1e-9
+        edges = np.concatenate([[w0], merged.reshape(-1), [w1]])
+        for g0, g1 in edges.reshape(-1, 2):
+            if g1 <= g0:
+                continue
+            mid = 0.5 * (g0 + g1)
+            i = int(np.searchsorted(span_starts, mid, side="right")) - 1
+            label = spans[i][2] if i >= 0 and spans[i][1] >= mid else "other"
+            gaps[label] += (g1 - g0) * 1e-9
+    n = max(len(device_ops), 1)
+    return Reduction(window_s=(w1 - w0) * 1e-9, busy_s=busy / n,
+                     scope_s={k: v / n for k, v in scope_s.items()},
+                     ops={k: v / n for k, v in ops.items()},
+                     gaps={k: v / n for k, v in gaps.items()},
+                     devices=len(device_ops))
+
+
+def reduce_dir(trace_dir: str) -> Reduction:
+    """Reduce the one ``.xplane.pb`` the profiler wrote under
+    ``trace_dir``."""
+    found = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    if len(found) != 1:
+        raise ValueError(f"{trace_dir}: expected one .xplane.pb, "
+                         f"found {found}")
+    return reduce_file(found[0])
