@@ -12,9 +12,9 @@ the controller sheds them in a spike — and asserts the recorder:
    armed/tripped edge, not one dump per hop),
 2. with ``reason == "shed_spike"`` and the window's rejected-counter
    delta visible in the artifact,
-3. whose stage attribution names a real stage of this hop program
-   (featurise/embed/encode — static cost-model weights here, since
-   cell hops are untraced in production),
+3. whose stage attribution names a host phase of the hop
+   (copy_in/dispatch/wait/copy_out), measured on every hop by
+   ``StreamLanes.hop`` whether or not anything traces it,
 4. and that the ring holds the last hops as a readable trace.
 
 Exits non-zero if any of that fails — CI runs this as the
@@ -40,6 +40,7 @@ from repro.configs import registry
 from repro.models import kwt
 from repro.stream import detector as det
 from repro.stream import features
+from repro.telemetry.cell import HOP_PHASES
 
 SLOTS = 4
 SPIKE = 6          # streams shed in the injected incident
@@ -112,9 +113,9 @@ def main(argv=None) -> int:
               f"dump reason: {art['reason']}")
         check(art["admission"]["rejected_in_window"] >= SPIKE,
               f"window shed delta: {art['admission']['rejected_in_window']}")
-        check(att["slowest_stage"] in ("featurise", "embed", "encode",
-                                       "unpack"),
-              f"slow hops attributed to stage {att['slowest_stage']!r} "
+        check(att["method"] == "measured-spans"
+              and att["slowest_stage"] in HOP_PHASES,
+              f"slow hops attributed to phase {att['slowest_stage']!r} "
               f"({att['method']}: {att['stage_ms']})")
         check(art["window_hops"] > 0 and len(art["trace"])
               == art["window_hops"],
@@ -129,7 +130,7 @@ def main(argv=None) -> int:
             print(f"  - {m}", file=sys.stderr)
         return 1
     print("\nflight drill passed: shed spike detected, dumped once, "
-          "attributed to a named stage.")
+          "attributed to a measured hop phase.")
     return 0
 
 

@@ -44,7 +44,8 @@ from repro.launch import mesh as meshlib
 from repro.stream import detector as det
 from repro.stream import engine as stream_engine
 from repro.telemetry import flight as flight_mod
-from repro.telemetry.cell import make_cell_metrics
+from repro.telemetry.cell import (HOP_PHASE_SPANS, HOP_PHASES, HOP_SPAN,
+                                  make_cell_metrics)
 
 
 class ServeCell:
@@ -194,23 +195,13 @@ class StreamLanes:
             return state, dstate, {**events, "logits": logits}
 
         self._joint = None if pipelined else jax.jit(joint)
-        if cell.flight is not None and cell.flight.stage_weights is None:
-            # static fallback attribution for flight dumps: the cost
-            # model's roofline-weighted stage split of exactly this hop
-            # program (lazy: traced only if a dump ever happens)
-            def _weights(eng=eng, fcfg=fcfg, k=chunk_hops,
-                         fi=feature_ingest):
-                from repro import perf
-                rep = perf.stream_hop_cost(eng, fcfg, batch=1,
-                                           chunk_hops=k, feature_ingest=fi)
-                return rep.stage_weights(perf.host_machine())
-            cell.flight.stage_weights = _weights
         self._det = jax.jit(lambda ds, lg, warm: det.detector_step(
             ds, stream_engine.posteriors(lg), dcfg, warm=warm)) \
             if pipelined else None
         self._reset = jax.jit(lambda s, ds, lane: (
             stream_engine.reset_lane(s, lane),
             det.detector_reset_lane(ds, lane)))
+        self._seq = 0                      # hops served (the span's seq)
 
     @property
     def chunk_samples(self) -> int:
@@ -257,26 +248,57 @@ class StreamLanes:
         ``ingest`` ([slots] ints) overrides the per-lane hop accounting
         for steps whose trailing chunk is zero-padded past a stream's
         end (a degraded-width step need not divide the stream length);
-        default: ``chunk_hops`` for every active lane."""
+        default: ``chunk_hops`` for every active lane.
+
+        Each call is one ``cell.hop`` span with a child per host phase
+        (``telemetry.cell.HOP_PHASES``); the phase times go to
+        ``cell_hop_phase_seconds_total`` and the flight recorder, always,
+        and the spans to the active tracer or profiler, when there is
+        one."""
         m = self.cell.metrics
-        t0 = time.perf_counter()
-        chunk = jnp.asarray(chunk)
-        p = self.cell.handle.live_params()
-        if self._joint is not None:
-            self.state, self.dstate, events = self._joint(
-                p, self.state, self.dstate, chunk)
-        else:
-            self.state, window = self._pipe._feat(p, self.state, chunk)
-            logits = self._pipe._enc(p, window)
-            warm = self.state["embed"]["count"] >= \
-                stream_engine.window_frames(self.cell.engine.exec_cfg)
-            self.dstate, events = self._det(self.dstate, logits, warm)
-            events = {**events, "logits": logits}
-        events = jax.tree.map(np.asarray, jax.block_until_ready(events))
-        dur_ms = 1e3 * (time.perf_counter() - t0)
+        clock = time.perf_counter_ns
+        copy_in, dispatch, wait, copy_out = HOP_PHASE_SPANS
+        t0 = clock()
+        with telemetry.span(HOP_SPAN, {"seq": self._seq}):
+            with telemetry.span(copy_in):
+                bytes_in = chunk.nbytes
+                chunk = jnp.asarray(chunk)
+            t1 = clock()
+            with telemetry.span(dispatch):
+                events = self._dispatch(chunk)
+            t2 = clock()
+            with telemetry.span(wait):
+                jax.block_until_ready(events)
+            t3 = clock()
+            with telemetry.span(copy_out):
+                events = jax.tree.map(np.asarray, events)
+            t4 = clock()
+        self._seq += 1
+        phase_ns = (t1 - t0, t2 - t1, t3 - t2, t4 - t3)
+        for name, ns in zip(HOP_PHASES, phase_ns):
+            m.hop_phase_s[name].inc(ns * 1e-9)
+        m.hop_bytes_in.inc(bytes_in)
+        m.hop_bytes_out.inc(sum(a.nbytes for a in events.values()))
+        dur_ms = (t4 - t0) * 1e-6
         m.hop_ms.observe(dur_ms)
         m.hops.inc(int(np.sum(ingest)) if ingest is not None
                    else self.chunk_hops * self.n_active)
         if self.cell.flight is not None:
-            self.cell.flight.record_hop(dur_ms)
+            self.cell.flight.record_hop(dur_ms, spans={
+                name: ns * 1e-6 for name, ns in zip(HOP_PHASES, phase_ns)})
         return events
+
+    def _dispatch(self, chunk) -> dict:
+        """Enqueue one hop's programs on the device ``chunk``; returns
+        the events and logits as device arrays, not yet computed."""
+        p = self.cell.handle.live_params()
+        if self._joint is not None:
+            self.state, self.dstate, events = self._joint(
+                p, self.state, self.dstate, chunk)
+            return events
+        self.state, window = self._pipe._feat(p, self.state, chunk)
+        logits = self._pipe._enc(p, window)
+        warm = self.state["embed"]["count"] >= \
+            stream_engine.window_frames(self.cell.engine.exec_cfg)
+        self.dstate, events = self._det(self.dstate, logits, warm)
+        return {**events, "logits": logits}

@@ -215,8 +215,7 @@ def _serve(cell, sources, queue, fcfg, dcfg, chunk_hops, met):
                 chunk[i, :n] = a[offset[i]:offset[i] + n]
                 offset[i] += n
                 ingest[i] = n // fcfg.hop_len
-        with telemetry.span("hop", {"backend": eng.backend_name}):
-            events = lanes.hop(chunk, ingest=ingest)
+        events = lanes.hop(chunk, ingest=ingest)   # opens its own cell.hop
         with telemetry.span("detector"):
             for i in range(B):
                 sid = active[i]

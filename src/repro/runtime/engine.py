@@ -234,7 +234,7 @@ class Engine:
             return step(self.live_params(), state, chunk)
         with tr.span("stream_step", {"backend": self.backend.name}):
             lp = self._live_traced(tr)
-            with tr.span("hop"):
+            with tr.span("step"):
                 return jax.block_until_ready(step(lp, state, chunk))
 
     # -- LM serving entry points ------------------------------------------

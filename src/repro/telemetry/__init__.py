@@ -3,7 +3,8 @@
 Three layers (see README §repro.telemetry):
 
 * :mod:`repro.telemetry.trace`   — host-side nested spans -> Chrome/Perfetto
-  trace-event JSON; free when disabled.
+  trace-event JSON, and into the JAX profiler's trace while it collects;
+  free when neither is on.
 * :mod:`repro.telemetry.metrics` — counters / gauges / ring-reservoir
   histograms with Prometheus-text + JSON export and the shared
   :func:`latency_summary` schema; :func:`log` structured log lines.
@@ -12,7 +13,7 @@ Three layers (see README §repro.telemetry):
   program (int8 saturation, LUT out-of-domain fractions, Q8.24 headroom).
 * :mod:`repro.telemetry.flight`  — a bounded flight recorder for the
   serving cell: last-N-hops ring + anomaly-triggered post-mortem dumps
-  with cost-model stage attribution (see README §repro.perf).
+  with stage attribution of the slow hops by their measured host phases.
 
 :func:`annotate` names a stage *inside* a jitted program (a
 ``jax.named_scope`` pass-through): metadata-only, shows up in jaxprs /

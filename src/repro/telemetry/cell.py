@@ -9,6 +9,17 @@ and export through the registry's usual Prometheus/JSON paths.
 
 Counters end in ``_total``; admission decisions carry a ``decision``
 label so one metric name covers admitted / degraded / rejected lanes.
+
+The span names of the stream hop live here too: ``StreamLanes.hop``
+opens ``cell.hop`` (args: ``seq``, the hop index) around four children,
+one per host phase of a hop, and adds each phase's wall time to
+``cell_hop_phase_seconds_total{phase=...}``:
+
+* ``copy_in``  -- the host chunk to the device (``jnp.asarray``);
+* ``dispatch`` -- the live operand tree and the enqueue of the hop's
+  programs;
+* ``wait``     -- blocking until the hop's events are computed;
+* ``copy_out`` -- the events and logits to host numpy.
 """
 
 from __future__ import annotations
@@ -16,6 +27,10 @@ from __future__ import annotations
 import dataclasses
 
 from repro.telemetry.metrics import Counter, Gauge, Histogram, Registry
+
+HOP_SPAN = "cell.hop"
+HOP_PHASES = ("copy_in", "dispatch", "wait", "copy_out")
+HOP_PHASE_SPANS = tuple(f"{HOP_SPAN}.{p}" for p in HOP_PHASES)
 
 
 @dataclasses.dataclass
@@ -39,6 +54,9 @@ class CellMetrics:
     tokens: Counter           # cell_tokens_total (LM tokens decoded)
     prefill_tokens: Counter   # cell_prefill_tokens_total (joined prompts)
     hop_ms: Histogram         # cell_hop_latency_ms
+    hop_phase_s: dict         # phase -> cell_hop_phase_seconds_total{phase}
+    hop_bytes_in: Counter     # cell_hop_transfer_bytes_total{direction="in"}
+    hop_bytes_out: Counter    # ...{direction="out"}
     decode_ms: Histogram      # cell_decode_latency_ms
     prefill_ms: Histogram     # cell_prefill_latency_ms
     latency_budget: Gauge     # cell_latency_budget_ms (SLO; 0 = unset)
@@ -53,6 +71,8 @@ class CellMetrics:
 def make_cell_metrics(registry: Registry) -> CellMetrics:
     """Register (or fetch) the full ``cell_*`` instrument set."""
     adm = "admission decisions for offered lanes"
+    phase = "stream hop wall time by host phase"
+    xfer = "bytes a stream hop moves between host and device"
     return CellMetrics(
         joins=registry.counter("cell_lane_joins_total",
                                "lanes joined into the batch in flight"),
@@ -78,6 +98,13 @@ def make_cell_metrics(registry: Registry) -> CellMetrics:
                                         "prompt tokens prefilled at join"),
         hop_ms=registry.histogram("cell_hop_latency_ms",
                                   "stream hop wall time", unit="ms"),
+        hop_phase_s={p: registry.counter("cell_hop_phase_seconds_total",
+                                         phase, labels={"phase": p})
+                     for p in HOP_PHASES},
+        hop_bytes_in=registry.counter("cell_hop_transfer_bytes_total", xfer,
+                                      labels={"direction": "in"}),
+        hop_bytes_out=registry.counter("cell_hop_transfer_bytes_total",
+                                       xfer, labels={"direction": "out"}),
         decode_ms=registry.histogram("cell_decode_latency_ms",
                                      "LM decode step wall time", unit="ms"),
         prefill_ms=registry.histogram("cell_prefill_latency_ms",

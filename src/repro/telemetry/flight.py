@@ -23,10 +23,10 @@ per hop):
 The dump is the debugging bundle an operator wants *after* the
 incident: the hop ring (a trace), admission/swap counter deltas, the
 full metric snapshot, and a **stage attribution** of the slow hops —
-measured span means when the hops carried spans, otherwise the static
-roofline-weighted stage split from
-:func:`repro.perf.cost.stream_hop_cost` — naming the stage that owns
-the regression (``"encode"``, ``"unpack"``, ...).
+measured span means when the hops carried spans (``StreamLanes.hop``
+records its host phases, ``copy_in`` / ``dispatch`` / ``wait`` /
+``copy_out``, on every hop), otherwise caller-supplied static stage
+weights — naming the stage that owns the regression.
 """
 
 from __future__ import annotations
@@ -59,7 +59,7 @@ class HopRecord:
     seq: int                     # monotone hop index (never wraps)
     t: float                     # recorder clock at observation
     duration_ms: float
-    spans: Optional[dict]        # per-stage ms, when the hop was traced
+    spans: Optional[dict]        # per-stage ms, when the caller measured them
     rejected: float
     swap_failures: float
     queue_depth: float
@@ -79,7 +79,8 @@ class FlightRecorder:
     zero-arg callable returning one (resolved lazily at first dump, so
     wiring the recorder costs nothing on the hot path) — is the static
     fallback attribution for hops recorded without spans.
-    ``StreamLanes`` wires it from the cost model automatically.
+    ``StreamLanes`` records measured phase spans with every hop, so its
+    dumps never fall back.
     """
 
     def __init__(self, metrics, config: Optional[FlightConfig] = None,
@@ -176,8 +177,8 @@ class FlightRecorder:
         Slow = over budget when one is set, else above 2× the window
         median.  Attribution prefers measured spans (mean per stage over
         the slow hops); hops recorded without spans fall back to the
-        static cost-model stage weights scaled by the mean slow
-        duration.  ``slowest_stage`` is the argmax either way.
+        static stage weights scaled by the mean slow duration.
+        ``slowest_stage`` is the argmax either way.
         """
         win = self.window()
         if not win:

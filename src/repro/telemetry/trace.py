@@ -5,17 +5,19 @@ The paper's 5x story started from per-op clock-cycle attribution (Figs
 repo's analogue for Engine plans.  A :class:`Tracer` records nested
 ``span("unpack")`` / ``span("encode")`` / ... context managers as Chrome
 trace-event *complete* events (``ph: "X"``, microsecond ``ts``/``dur``)
-that load directly into ``chrome://tracing`` / Perfetto, plus an optional
-``jax.profiler`` annotation pass-through so the same span names appear in
-XLA device profiles.
+that load directly into ``chrome://tracing`` / Perfetto.  Whenever the JAX
+profiler is collecting (``jax.profiler.start_trace`` / ``trace``), every
+span also opens a ``jax.profiler.TraceAnnotation`` of the same name, with
+its args as metadata, so the spans land in the profiler's own trace
+beside its device events -- with or without a :class:`Tracer` installed.
 
 Design constraints (tests/test_telemetry.py):
 
 * **Disabled fast path is free.**  ``telemetry.span(name)`` with no
-  active tracer returns one shared no-op context manager — no object,
-  tuple or dict is allocated per call, so instrumented hot paths
-  (``Engine.forward``) cost one global read + ``None`` check when
-  tracing is off.
+  active tracer and no profiler collecting returns one shared no-op
+  context manager — no object, tuple or dict is allocated per call, so
+  instrumented hot paths (``Engine.forward``, ``StreamLanes.hop``) cost
+  one global read and one profiler-state check when tracing is off.
 * **Spans measure device work, not dispatch.**  Callers fence jitted
   results with ``jax.block_until_ready`` *inside* the span when (and
   only when) a tracer is active; async dispatch is preserved otherwise.
@@ -31,6 +33,11 @@ import json
 import os
 import threading
 import time
+
+from jax.profiler import TraceAnnotation
+
+# True while a JAX profiler session collects host events.
+profiling = TraceAnnotation.is_enabled
 
 
 class _NoopSpan:
@@ -63,9 +70,8 @@ class _Span:
     def __enter__(self):
         tr = self._tracer
         tr._stack().append(self.name)
-        if tr.profiler:
-            import jax.profiler
-            self._annotation = jax.profiler.TraceAnnotation(self.name)
+        if profiling():
+            self._annotation = _annotation(self.name, self.args)
             self._annotation.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
@@ -84,17 +90,15 @@ class _Span:
         return False
 
 
+def _annotation(name: str, args: dict | None) -> TraceAnnotation:
+    return TraceAnnotation(name, **args) if args else TraceAnnotation(name)
+
+
 class Tracer:
-    """Collects spans as Chrome trace-event JSON (``ph: "X"`` events).
+    """Collects spans as Chrome trace-event JSON (``ph: "X"`` events)."""
 
-    ``profiler=True`` additionally wraps every span in a
-    ``jax.profiler.TraceAnnotation`` so the names show up in XLA device
-    traces captured by ``jax.profiler.trace``.
-    """
-
-    def __init__(self, *, profiler: bool = False):
+    def __init__(self):
         self.events: list[dict] = []
-        self.profiler = profiler
         self._epoch = time.perf_counter_ns()
         self._local = threading.local()
         self._lock = threading.Lock()
@@ -176,10 +180,10 @@ def span_coverage(tracer_or_events, parent: str,
 _ACTIVE: Tracer | None = None
 
 
-def enable(tracer: Tracer | None = None, *, profiler: bool = False) -> Tracer:
+def enable(tracer: Tracer | None = None) -> Tracer:
     """Install ``tracer`` (or a fresh one) as the active tracer."""
     global _ACTIVE
-    _ACTIVE = tracer if tracer is not None else Tracer(profiler=profiler)
+    _ACTIVE = tracer if tracer is not None else Tracer()
     return _ACTIVE
 
 
@@ -195,22 +199,26 @@ def active_tracer() -> Tracer | None:
 
 
 def span(name: str, args: dict | None = None):
-    """Span under the active tracer, or the shared no-op when disabled.
+    """Span under the active tracer (which also annotates the profiler's
+    trace while it collects), else a bare profiler annotation while the
+    JAX profiler collects, else the shared no-op.
 
     The disabled path allocates nothing: it returns the module-level
     ``NOOP_SPAN`` singleton (fixed-arity ``__exit__``, ``__slots__``),
     which is what keeps un-traced ``Engine.forward`` calls free.
     """
     tr = _ACTIVE
-    if tr is None:
-        return NOOP_SPAN
-    return tr.span(name, args)
+    if tr is not None:
+        return tr.span(name, args)
+    if profiling():
+        return _annotation(name, args)
+    return NOOP_SPAN
 
 
 @contextlib.contextmanager
-def tracing(*, profiler: bool = False):
+def tracing():
     """Scoped enable: ``with tracing() as tr: ... tr.save(path)``."""
-    tr = enable(profiler=profiler)
+    tr = enable()
     try:
         yield tr
     finally:

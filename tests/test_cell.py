@@ -5,6 +5,7 @@ partial-write tolerance)."""
 
 import json
 import os
+import time
 import types
 
 import jax
@@ -516,6 +517,125 @@ def test_stream_lanes_feature_ingest_matches_audio(kwt_setup):
             ea, ef = a.hop(c), f.hop(frames)
             np.testing.assert_array_equal(ea["score"], ef["score"])
             np.testing.assert_array_equal(ea["fired"], ef["fired"])
+
+
+# ---------------------------------------------------------------------------
+# StreamLanes.hop: spans and phase counters
+# ---------------------------------------------------------------------------
+
+def _joined_lanes(kwt_setup, **cell_kw):
+    cfg, params = kwt_setup
+    cell = cellmod.ServeCell(
+        runtime.compile_model(cfg, params, backend="float"), slots=2,
+        registry=telemetry.Registry(), **cell_kw)
+    lanes = cell.stream_lanes(FCFG, det.DetectorConfig())
+    lanes.join(0)
+    lanes.join(1)
+    return cell, lanes
+
+
+def _chunks(n, seed=5):
+    rng = np.random.RandomState(seed)
+    return [rng.randn(2, HOP).astype(np.float32) for _ in range(n)]
+
+
+@pytest.mark.filterwarnings("ignore:builtin type event_stats")
+def test_stream_hop_spans_reach_the_profiler_trace(kwt_setup, tmp_path):
+    """Under the JAX profiler, with no tracer installed, every hop writes
+    ``cell.hop`` (args: seq) with its four phase children, nested and in
+    order, into the trace's host plane."""
+    from jax.profiler import ProfileData
+
+    from repro.telemetry.cell import HOP_PHASE_SPANS, HOP_SPAN
+    cell, lanes = _joined_lanes(kwt_setup)
+    with cell:
+        lanes.hop(_chunks(1)[0])                     # compile outside
+        assert telemetry.active_tracer() is None
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            for c in _chunks(3):
+                lanes.hop(c)
+        finally:
+            jax.profiler.stop_trace()
+    (path,) = [os.path.join(d, f) for d, _, fs in os.walk(tmp_path)
+               for f in fs if f.endswith(".xplane.pb")]
+    events = [(ev.start_ns, ev.start_ns + ev.duration_ns, ev.name,
+               dict(ev.stats))
+              for plane in ProfileData.from_file(path).planes
+              if plane.name.startswith("/host:")
+              for line in plane.lines for ev in line.events
+              if ev.name.startswith(HOP_SPAN)]
+    hops = sorted(e for e in events if e[2] == HOP_SPAN)
+    assert [e[3]["seq"] for e in hops] == [1, 2, 3]
+    for h0, h1, _, _ in hops:
+        inside = sorted(e for e in events
+                        if e[2] != HOP_SPAN and h0 <= e[0] and e[1] <= h1)
+        assert tuple(e[2] for e in inside) == HOP_PHASE_SPANS
+        for prev, nxt in zip(inside, inside[1:]):
+            assert prev[1] <= nxt[0]                 # in order, no overlap
+    assert len(events) == 3 * (1 + len(HOP_PHASE_SPANS))
+
+
+def test_stream_hop_spans_under_a_tracer(kwt_setup):
+    """With a Tracer active the same names are Chrome events, the phases
+    children of ``cell.hop``, and they account for its wall time."""
+    from repro.telemetry.cell import HOP_PHASE_SPANS, HOP_SPAN
+    cell, lanes = _joined_lanes(kwt_setup)
+    with cell:
+        lanes.hop(_chunks(1)[0])
+        with telemetry.tracing() as tr:
+            for c in _chunks(3):
+                lanes.hop(c)
+    hops = [e for e in tr.events if e["name"] == HOP_SPAN]
+    assert [e["args"]["seq"] for e in hops] == [1, 2, 3]
+    kids = [e["name"] for e in tr.events
+            if e.get("args", {}).get("parent") == HOP_SPAN]
+    assert kids == list(HOP_PHASE_SPANS) * 3
+    assert telemetry.span_coverage(tr, HOP_SPAN) >= 0.9
+
+
+def test_stream_hop_phase_and_transfer_counters(kwt_setup):
+    """The always-on counters: the four phases sum to the hop's own wall
+    time (no more than the caller saw), and the bytes moved in and out
+    are the chunks' and the returned events'."""
+    cell, lanes = _joined_lanes(kwt_setup)
+    chunks = _chunks(5)
+    wall, out_bytes = 0.0, 0
+    with cell:
+        for c in chunks:
+            t = time.perf_counter()
+            events = lanes.hop(c)
+            wall += time.perf_counter() - t
+            out_bytes += sum(np.asarray(a).nbytes for a in events.values())
+    m = cell.metrics
+    phase_s = sum(c.value for c in m.hop_phase_s.values())
+    assert set(m.hop_phase_s) == {"copy_in", "dispatch", "wait", "copy_out"}
+    assert all(c.value > 0 for c in m.hop_phase_s.values())
+    assert phase_s <= wall
+    assert phase_s == pytest.approx(1e-3 * sum(m.hop_ms.values()), rel=1e-9)
+    assert m.hop_bytes_in.value == len(chunks) * chunks[0].nbytes
+    assert m.hop_bytes_out.value == out_bytes
+    prom = cell.metrics.hop_phase_s["wait"].to_prometheus()
+    assert 'cell_hop_phase_seconds_total{phase="wait"}' in prom
+
+
+def test_flight_dump_attributes_measured_hop_phases(kwt_setup, tmp_path,
+                                                    monkeypatch):
+    """A cell built with ``flight=True`` attributes its slow hops by the
+    phases each hop measured, not by a static cost model."""
+    from repro.telemetry.cell import HOP_PHASES
+    monkeypatch.chdir(tmp_path)
+    cell, lanes = _joined_lanes(kwt_setup, flight=True)
+    cell.metrics.latency_budget.set(1e-9)            # every hop burns
+    with cell:
+        for c in _chunks(cell.flight.cfg.min_hops):
+            lanes.hop(c)
+    assert len(cell.flight.dumps) == 1
+    with open(cell.flight.dumps[0]) as f:
+        att = json.load(f)["attribution"]
+    assert att["method"] == "measured-spans"
+    assert set(att["stage_ms"]) == set(HOP_PHASES)
+    assert att["slowest_stage"] in HOP_PHASES
 
 
 def test_cell_swap_under_streaming_drops_nothing(kwt_setup, tmp_path):

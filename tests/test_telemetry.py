@@ -13,7 +13,9 @@ The PR-7 contracts:
   ``Engine.forward`` (one shared no-op span, one global read).
 """
 
+import glob
 import json
+import os
 import tracemalloc
 
 import jax
@@ -26,6 +28,7 @@ from repro.configs import registry
 from repro.models import kwt
 from repro.telemetry import check as tcheck
 from repro.telemetry import taps
+from repro.telemetry import trace as trace_mod
 
 KEY = jax.random.PRNGKey(0)
 CFG = registry.get("kwt-tiny").config
@@ -237,6 +240,77 @@ def test_disabled_span_allocates_nothing():
     grew = [st for st in snap2.compare_to(snap1, "lineno")
             if st.size_diff > 0 and "repro/telemetry" in str(st.traceback)]
     assert not grew, f"disabled span path allocated: {grew}"
+
+
+def test_span_is_noop_with_no_tracer_and_no_profiler():
+    telemetry.disable()
+    assert not trace_mod.profiling()
+    assert telemetry.span("cell.hop", {"seq": 0}) is telemetry.NOOP_SPAN
+
+
+def _host_events(trace_dir):
+    """(start, end, name, stats) of every host event in the one
+    ``.xplane.pb`` the profiler wrote under ``trace_dir``."""
+    from jax.profiler import ProfileData
+    (path,) = glob.glob(os.path.join(str(trace_dir), "**", "*.xplane.pb"),
+                        recursive=True)
+    return sorted((ev.start_ns, ev.start_ns + ev.duration_ns, ev.name,
+                   dict(ev.stats))
+                  for plane in ProfileData.from_file(path).planes
+                  if plane.name.startswith("/host:")
+                  for line in plane.lines for ev in line.events)
+
+
+@pytest.mark.filterwarnings("ignore:builtin type event_stats")
+def test_span_annotates_the_profiler_trace_without_a_tracer(tmp_path):
+    """While the JAX profiler collects, ``span`` opens a TraceAnnotation
+    (args as metadata) even with no tracer installed; before and after,
+    it is the shared no-op."""
+    telemetry.disable()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        assert trace_mod.profiling()
+        outer = telemetry.span("outer", {"seq": 7})
+        assert outer is not telemetry.NOOP_SPAN
+        with outer:
+            with telemetry.span("inner"):
+                sum(range(1000))
+    finally:
+        jax.profiler.stop_trace()
+    assert telemetry.span("outer") is telemetry.NOOP_SPAN
+    evs = {e[2]: e for e in _host_events(tmp_path)
+           if e[2] in ("outer", "inner")}
+    assert evs["outer"][3] == {"seq": 7}
+    assert evs["outer"][0] <= evs["inner"][0] <= evs["inner"][1] \
+        <= evs["outer"][1]
+
+
+@pytest.mark.filterwarnings("ignore:builtin type event_stats")
+def test_tracer_span_also_annotates_the_profiler_trace(tmp_path):
+    """One span system: under a Tracer and the profiler at once, a span is
+    both a Chrome event and a profiler annotation of the same name."""
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with telemetry.tracing() as tr:
+            with telemetry.span("forward", {"backend": "float"}):
+                with tr.span("encode"):
+                    pass
+    finally:
+        jax.profiler.stop_trace()
+    assert [e["name"] for e in tr.events] == ["encode", "forward"]
+    names = [e[2] for e in _host_events(tmp_path)]
+    assert names.count("forward") == 1 and names.count("encode") == 1
+
+
+def test_tracer_takes_no_profiler_flag():
+    """The profiler's own state decides annotation; there is no flag."""
+    with pytest.raises(TypeError):
+        telemetry.Tracer(profiler=True)
+    with pytest.raises(TypeError):
+        telemetry.enable(profiler=True)
+    with pytest.raises(TypeError):
+        with telemetry.tracing(profiler=True):
+            pass
 
 
 def test_engine_forward_disabled_path_unchanged(params, mfcc):
