@@ -26,6 +26,7 @@ are thin CLIs over this class.
 from __future__ import annotations
 
 import contextlib
+import math
 import time
 from typing import Any, Optional
 
@@ -181,6 +182,16 @@ class StreamLanes:
         self._pipe = pipeline_mod.HopPipeline(
             cell.handle, fcfg, keep_features=keep_features, donate=False) \
             if pipelined else None
+        self._events_like = None     # the events' layout, set by ``detect``
+
+        def detect(dstate, logits, warm):
+            # the hop's last stage in either program: the detector, then
+            # its events and the logits packed for one transfer out
+            dstate, events = det.detector_step(
+                dstate, stream_engine.posteriors(logits), dcfg, warm=warm)
+            packed, self._events_like = pack_events(
+                {**events, "logits": logits})
+            return dstate, packed
 
         def joint(params, state, dstate, chunk):
             if feature_ingest:
@@ -189,15 +200,11 @@ class StreamLanes:
             else:
                 state, logits = stream_engine.stream_step(params, state,
                                                           chunk, cfg, fcfg)
-            dstate, events = det.detector_step(
-                dstate, stream_engine.posteriors(logits), dcfg,
-                warm=stream_engine.warm(state))
-            return state, dstate, {**events, "logits": logits}
+            return (state, *detect(dstate, logits,
+                                   stream_engine.warm(state)))
 
         self._joint = None if pipelined else jax.jit(joint)
-        self._det = jax.jit(lambda ds, lg, warm: det.detector_step(
-            ds, stream_engine.posteriors(lg), dcfg, warm=warm)) \
-            if pipelined else None
+        self._det = jax.jit(detect) if pipelined else None
         self._reset = jax.jit(lambda s, ds, lane: (
             stream_engine.reset_lane(s, lane),
             det.detector_reset_lane(ds, lane)))
@@ -243,7 +250,9 @@ class StreamLanes:
         [slots, chunk_hops, F] under ``feature_ingest``; returns
         the detector events ``{"fired": [B], "score": [B], ...}`` and
         the served ``"logits"`` [B, n_classes] (host numpy — the per-hop
-        sync point, as in the pre-cell server).
+        sync point, as in the pre-cell server).  They come back in one
+        device-to-host transfer, started as soon as the hop is enqueued
+        (:func:`pack_events`).
 
         ``ingest`` ([slots] ints) overrides the per-lane hop accounting
         for steps whose trailing chunk is zero-padded past a stream's
@@ -265,20 +274,24 @@ class StreamLanes:
                 chunk = jnp.asarray(chunk)
             t1 = clock()
             with telemetry.span(dispatch):
-                events = self._dispatch(chunk)
+                packed = self._dispatch(chunk)
+                packed.copy_to_host_async()
             t2 = clock()
             with telemetry.span(wait):
-                jax.block_until_ready(events)
+                packed.block_until_ready()
             t3 = clock()
             with telemetry.span(copy_out):
-                events = jax.tree.map(np.asarray, events)
+                events = unpack_events(np.asarray(packed),
+                                       self._events_like)
             t4 = clock()
         self._seq += 1
         phase_ns = (t1 - t0, t2 - t1, t3 - t2, t4 - t3)
         for name, ns in zip(HOP_PHASES, phase_ns):
             m.hop_phase_s[name].inc(ns * 1e-9)
         m.hop_bytes_in.inc(bytes_in)
-        m.hop_bytes_out.inc(sum(a.nbytes for a in events.values()))
+        m.hop_bytes_out.inc(packed.nbytes)
+        m.hop_fetches.inc(1 if packed.is_fully_replicated
+                          else len(packed.sharding.device_set))
         dur_ms = (t4 - t0) * 1e-6
         m.hop_ms.observe(dur_ms)
         m.hops.inc(int(np.sum(ingest)) if ingest is not None
@@ -288,17 +301,47 @@ class StreamLanes:
                 name: ns * 1e-6 for name, ns in zip(HOP_PHASES, phase_ns)})
         return events
 
-    def _dispatch(self, chunk) -> dict:
+    def _dispatch(self, chunk) -> jax.Array:
         """Enqueue one hop's programs on the device ``chunk``; returns
-        the events and logits as device arrays, not yet computed."""
+        the packed events and logits, not yet computed."""
         p = self.cell.handle.live_params()
         if self._joint is not None:
-            self.state, self.dstate, events = self._joint(
+            self.state, self.dstate, packed = self._joint(
                 p, self.state, self.dstate, chunk)
-            return events
+            return packed
         self.state, window = self._pipe._feat(p, self.state, chunk)
         logits = self._pipe._enc(p, window)
         warm = self.state["embed"]["count"] >= \
             stream_engine.window_frames(self.cell.engine.exec_cfg)
-        self.dstate, events = self._det(self.dstate, logits, warm)
-        return {**events, "logits": logits}
+        self.dstate, packed = self._det(self.dstate, logits, warm)
+        return packed
+
+
+def pack_events(events: dict) -> tuple[jax.Array, Any]:
+    """Every leaf of ``events``, bitcast to bytes (bool as ``uint8``) in
+    ``jax.tree.flatten`` order, as one flat ``uint8`` array: the hop's
+    outputs leave the device in one transfer, and unpadded.  Also returns
+    the tree's shapes and dtypes (``jax.ShapeDtypeStruct`` leaves), the
+    layout :func:`unpack_events` reads; call it where ``events`` is
+    traced, and keep the layout from there."""
+    leaves = jax.tree.leaves(events)
+    packed = jnp.concatenate([
+        (x.astype(jnp.uint8) if x.dtype == jnp.bool_
+         else jax.lax.bitcast_convert_type(x, jnp.uint8)).reshape(-1)
+        for x in leaves])
+    like = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
+                        events)
+    return packed, like
+
+
+def unpack_events(packed: np.ndarray, like) -> dict:
+    """The tree :func:`pack_events` packed, as views of the host buffer
+    ``packed``: the same keys, shapes, dtypes and bits."""
+    leaves, treedef = jax.tree.flatten(like)
+    out, off = [], 0
+    for x in leaves:
+        n = math.prod(x.shape) * np.dtype(x.dtype).itemsize
+        out.append(packed[off:off + n].view(x.dtype).reshape(x.shape))
+        off += n
+    assert off == packed.size, (off, packed.size)
+    return jax.tree.unflatten(treedef, out)

@@ -19,7 +19,12 @@ one per host phase of a hop, and adds each phase's wall time to
 * ``dispatch`` -- the live operand tree and the enqueue of the hop's
   programs;
 * ``wait``     -- blocking until the hop's events are computed;
-* ``copy_out`` -- the events and logits to host numpy.
+* ``copy_out`` -- the end of the one transfer of the packed events and
+  logits, started at dispatch, and their unpacking into host numpy.
+
+``cell_hop_fetches_total`` counts the device-to-host transfers the hops
+issued (one per hop on one device), and
+``cell_hop_transfer_bytes_total{direction="out"}`` the bytes they moved.
 """
 
 from __future__ import annotations
@@ -57,6 +62,7 @@ class CellMetrics:
     hop_phase_s: dict         # phase -> cell_hop_phase_seconds_total{phase}
     hop_bytes_in: Counter     # cell_hop_transfer_bytes_total{direction="in"}
     hop_bytes_out: Counter    # ...{direction="out"}
+    hop_fetches: Counter      # cell_hop_fetches_total (device-to-host)
     decode_ms: Histogram      # cell_decode_latency_ms
     prefill_ms: Histogram     # cell_prefill_latency_ms
     latency_budget: Gauge     # cell_latency_budget_ms (SLO; 0 = unset)
@@ -105,6 +111,9 @@ def make_cell_metrics(registry: Registry) -> CellMetrics:
                                       labels={"direction": "in"}),
         hop_bytes_out=registry.counter("cell_hop_transfer_bytes_total",
                                        xfer, labels={"direction": "out"}),
+        hop_fetches=registry.counter(
+            "cell_hop_fetches_total",
+            "device-to-host transfers stream hops issued"),
         decode_ms=registry.histogram("cell_decode_latency_ms",
                                      "LM decode step wall time", unit="ms"),
         prefill_ms=registry.histogram("cell_prefill_latency_ms",

@@ -619,6 +619,158 @@ def test_stream_hop_phase_and_transfer_counters(kwt_setup):
     assert 'cell_hop_phase_seconds_total{phase="wait"}' in prom
 
 
+@pytest.mark.parametrize("pipelined", [False, True])
+def test_stream_hop_one_fetch_per_hop(kwt_setup, pipelined):
+    """Each hop moves its events and logits to the host in one transfer,
+    of exactly the leaves' bytes."""
+    cfg, params = kwt_setup
+    cell = cellmod.ServeCell(
+        runtime.compile_model(cfg, params, backend="float"), slots=2,
+        registry=telemetry.Registry())
+    chunks = _chunks(4)
+    with cell:
+        lanes = cell.stream_lanes(FCFG, det.DetectorConfig(),
+                                  pipelined=pipelined)
+        lanes.join(0)
+        leaf_bytes = sum(sum(a.nbytes for a in lanes.hop(c).values())
+                         for c in chunks)
+    m = cell.metrics
+    assert m.hop_fetches.value == len(chunks)
+    assert m.hop_bytes_out.value == leaf_bytes
+    assert "cell_hop_fetches_total" in m.hop_fetches.to_prometheus()
+
+
+def test_pack_events_round_trip_is_bit_exact():
+    """``pack_events`` -> host bytes -> ``unpack_events`` keeps every key,
+    shape, dtype and bit, NaN payloads and odd lengths included, and the
+    buffer holds the leaves' bytes and no padding."""
+    from repro.cell.cell import pack_events, unpack_events
+    rng = np.random.RandomState(6)
+    nan_payload = np.array([0x7FC00001, 0xFFC00000], np.uint32)
+    tree = {"fired": rng.rand(7) > 0.5,
+            "hop": np.int32(-3),
+            "logits": np.concatenate([
+                rng.randn(7, 3).astype(np.float32).ravel()[:19],
+                nan_payload.view(np.float32)]).reshape(7, 3),
+            "q": rng.randint(-128, 128, (5,)).astype(np.int8),
+            "score": np.array([np.inf, -np.inf, 0.0, -0.0, np.nan],
+                              np.float32)}
+    layout = {}
+
+    def pack(t):
+        packed, layout["like"] = pack_events(t)
+        return packed
+
+    packed = jax.jit(pack)(tree)
+    assert packed.dtype == jnp.uint8 and packed.ndim == 1
+    assert packed.nbytes == sum(np.asarray(a).nbytes for a in tree.values())
+    back = unpack_events(np.asarray(packed), layout["like"])
+    assert list(back) == list(tree)
+    for k, want in tree.items():
+        want = np.asarray(want)
+        assert back[k].shape == want.shape and back[k].dtype == want.dtype
+        assert back[k].tobytes() == want.tobytes(), k
+
+
+def _unpacked_hop(lanes, feature_ingest):
+    """The hop's programs as they were before packing, on the same lane
+    state: the events and logits as separate device outputs."""
+    eng = lanes.cell.engine
+    cfg, dcfg = eng.exec_cfg, lanes.dcfg
+
+    def detect(dstate, logits, warm):
+        dstate, events = det.detector_step(
+            dstate, stream_engine.posteriors(logits), dcfg, warm=warm)
+        return dstate, {**events, "logits": logits}
+
+    @jax.jit
+    def joint(params, state, dstate, chunk):
+        if feature_ingest:
+            state, logits = stream_engine.stream_step_frames(
+                params, state, chunk, cfg)
+        else:
+            state, logits = stream_engine.stream_step(params, state, chunk,
+                                                      cfg, FCFG)
+        return (state, *detect(dstate, logits, stream_engine.warm(state)))
+
+    detect_jit = jax.jit(detect)
+
+    def run(chunk):
+        p = lanes.cell.handle.live_params()
+        chunk = jnp.asarray(chunk)
+        if lanes._pipe is None:
+            return joint(p, lanes.state, lanes.dstate, chunk)[-1]
+        state, window = lanes._pipe._feat(p, lanes.state, chunk)
+        warm = state["embed"]["count"] >= stream_engine.window_frames(cfg)
+        return detect_jit(lanes.dstate, lanes._pipe._enc(p, window),
+                          warm)[-1]
+    return run
+
+
+def _assert_same_bits(got, want, what):
+    """Equal shape, dtype and bits, except that a NaN need only meet a
+    NaN: which of two NaN operands an add returns is the instruction's
+    choice, and XLA may order an add's operands differently in the
+    fusion that packs it (the packing's own round trip keeps every
+    payload: ``test_pack_events_round_trip_is_bit_exact``)."""
+    assert got.shape == want.shape and got.dtype == want.dtype, what
+    if np.issubdtype(want.dtype, np.floating):
+        nan = np.isnan(want)
+        np.testing.assert_array_equal(np.isnan(got), nan, err_msg=str(what))
+        got, want = got[~nan], want[~nan]
+    assert got.tobytes() == want.tobytes(), what
+
+
+@pytest.mark.parametrize("ingest,slots,classes,nonfinite", [
+    ("audio", 3, 2, False),
+    ("audio", 13, 35, True),
+    ("feature", 13, 35, False),
+    ("feature", 3, 2, True),
+    ("pipelined", 5, 35, False),
+    ("pipelined", 3, 2, True),
+])
+def test_hop_events_are_the_unpacked_programs_bit_for_bit(
+        kwt_setup, ingest, slots, classes, nonfinite):
+    """What ``hop`` returns from its one packed transfer is, key for key,
+    in shape, dtype and bits, what the hop's programs yield without
+    packing: at lane counts off every tile, at 2 and 35 classes, and with
+    NaN and infinite logits (the benchmark counts non-finite lane-hops
+    from them)."""
+    import dataclasses
+    cfg = dataclasses.replace(kwt_setup[0], n_classes=classes)
+    params = kwt.init_params(cfg, jax.random.PRNGKey(1))
+    if nonfinite:
+        params["head_b"] = jnp.asarray(
+            [np.inf, np.nan, -np.inf][:classes] + [0.0] * (classes - 3),
+            jnp.float32)
+    cell = cellmod.ServeCell(
+        runtime.compile_model(cfg, params, backend="float"), slots=slots,
+        registry=telemetry.Registry())
+    feature = ingest == "feature"
+    rng = np.random.RandomState(slots)
+    with cell:
+        lanes = cell.stream_lanes(FCFG, det.DetectorConfig(),
+                                  pipelined=ingest == "pipelined",
+                                  feature_ingest=feature)
+        for lane in range(slots - 1):                # the last lane idles
+            lanes.join(lane)
+        reference = _unpacked_hop(lanes, feature)
+        for step in range(3):
+            shape = (slots, 1, FCFG.n_mfcc) if feature else (slots, HOP)
+            chunk = rng.randn(*shape).astype(np.float32)
+            if nonfinite and step == 1:
+                chunk[0] = np.nan
+            want = jax.tree.map(np.asarray, reference(chunk))
+            got = lanes.hop(chunk)
+            assert list(got) == list(want)
+            assert set(got) == {"fired", "score", "hop", "logits"}
+            assert got["logits"].shape == (slots, classes)
+            for k in want:
+                _assert_same_bits(got[k], want[k], (k, step))
+        if nonfinite:
+            assert not np.isfinite(got["logits"]).all()
+
+
 def test_flight_dump_attributes_measured_hop_phases(kwt_setup, tmp_path,
                                                     monkeypatch):
     """A cell built with ``flight=True`` attributes its slow hops by the
