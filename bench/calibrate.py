@@ -3,17 +3,21 @@
 on the chip:
 
     python bench/calibrate.py --workload <cell> --seeds <n1> <n2> ... \\
-        [--seconds 2] [--program-bits 4] [--out <dir>]
+        [--seconds 2] [--program-bits 4] [--program-override KEY=VALUE]
+        [--out <dir>]
 
-For each seed it runs the cell at its own lane count with a short window,
-and prints the compared numbers of the program and of the controls: the
-reference one precision step below the stated numerics in the integer
-grids, the attention operands or the float intermediates, each in the
-program's place on the same inputs.  With ``--program-bits 4`` the
-program serves its own 4-bit weights, and its readings are another
-control's.  With ``--out`` the logits compared go to
-``<dir>/<cell>_<seed>.npz``.  The last line gives the largest program
-reading (the lower one) and the smallest of each control (the upper).
+For each seed it runs the cell at its own lane (or slot) count with a
+short window, and prints the compared numbers of the program and of the
+controls: the reference one precision step below the stated numerics in
+the integer grids or linear operands, the attention operands or the
+float intermediates (on the LM path: its weights), each in the program's
+place on the same inputs.  With ``--program-bits 4`` the program serves
+its own 4-bit weights, and with ``--program-override`` (LM path) a key of
+its configuration set apart from the file, such as a wrong
+``rope_theta``: its readings are then another control's.  With ``--out``
+the numbers compared go to ``<dir>/<cell>_<seed>.npz``.  The last line
+gives the largest program reading (the lower one) and the smallest of
+each control (the upper).
 """
 
 from __future__ import annotations
@@ -38,10 +42,16 @@ def main(argv=None) -> int:
     ap.add_argument("--program-bits", type=int, default=None,
                     help="serve the program's own weights at this width "
                          "(its lower-precision control) instead")
+    ap.add_argument("--program-override", action="append", default=[],
+                    metavar="KEY=VALUE",
+                    help="LM path: set a key of the program's configuration "
+                         "apart from the file (a planted fault)")
     ap.add_argument("--out", type=Path, default=None,
                     help="directory for the logits compared")
     args = ap.parse_args(argv)
 
+    overrides = {k: float(v) for k, v in
+                 (kv.split("=", 1) for kv in args.program_override)}
     import jax
     from yardstick import harness
     program, control = {}, {}
@@ -50,16 +60,20 @@ def main(argv=None) -> int:
         result, checks = harness.run(args.workload, seed, args.seconds,
                                      False, control=True,
                                      program_bits=args.program_bits,
+                                     program_overrides=overrides or None,
                                      record=record,
                                      log=lambda s: print(f"  {s}",
                                                          flush=True))
         if args.out is not None:
             args.out.mkdir(parents=True, exist_ok=True)
             leaves = jax.tree_util.tree_flatten_with_path(
-                record.pop("weights"))[0]
+                record.pop("weights", {}))[0]
             np.savez(args.out / f"{args.workload}_{seed}.npz", **record,
                      **{"w" + jax.tree_util.keystr(path): np.float32(leaf)
                         for path, leaf in leaves})
+        # drop this seed's compiled programs: each holds device memory
+        # while loaded, and the next seed's engine compiles its own
+        jax.clear_caches()
         row = {"workload": args.workload, "seed": seed,
                "correct": result["correct"],
                "program": {k: c["value"] for k, c in checks.items()},

@@ -1,5 +1,5 @@
 #!/usr/bin/env python3
-"""Lane sweep of one cell, in one process on the chip:
+"""Lane (or slot) sweep of one cell, in one process on the chip:
 
     python bench/sweep.py --workload <cell> --lanes 256 512 ... \\
         --seconds 3 --seed <n> [--limit-ms 10]
@@ -7,7 +7,9 @@
 Runs the cell's closed loop at each lane count and prints one JSON line
 each, then the largest lane count whose ``hop_p95_ms`` stays within the
 hop period (``--limit-ms``): the most streams one chip serves in real
-time.  The mix file's ``lanes`` is then set to that count by hand.
+time.  On the LM path the counts are decode slots, and the last line
+gives the most slots that ran without running out of device memory.
+The mix file's ``lanes`` or ``slots`` is then set to that count by hand.
 """
 
 from __future__ import annotations
@@ -31,20 +33,32 @@ def main(argv=None) -> int:
     ap.add_argument("--limit-ms", type=float, default=10.0)
     args = ap.parse_args(argv)
 
+    import jax
     from yardstick import harness
-    best = None
+    best, fits = None, None
     for n in args.lanes:
-        result, _ = harness.run(args.workload, args.seed, args.seconds,
-                                False, lanes=n,
-                                log=lambda s: print(f"  {s}", flush=True))
+        try:
+            result, _ = harness.run(args.workload, args.seed, args.seconds,
+                                    False, lanes=n,
+                                    log=lambda s: print(f"  {s}", flush=True))
+        except jax.errors.JaxRuntimeError as e:
+            if "RESOURCE_EXHAUSTED" not in str(e):
+                raise
+            print(json.dumps({"workload": args.workload, "lanes": n,
+                              "out_of_memory": str(e)[:300]}), flush=True)
+            break
+        fits = n
         m = {k: v["value"] for k, v in result["metrics"].items()}
         print(json.dumps({"workload": args.workload, "lanes": n,
                           "correct": result["correct"], **m,
+                          "memory_peak_bytes":
+                          result["device"]["memory_peak_bytes"],
                           "checks": result["checks"]}), flush=True)
-        if m["hop_p95_ms"] <= args.limit_ms:
+        if m.get("hop_p95_ms", float("inf")) <= args.limit_ms:
             best = n
     print(json.dumps({"workload": args.workload, "real_time_lanes": best,
-                      "limit_ms": args.limit_ms}), flush=True)
+                      "limit_ms": args.limit_ms, "most_that_ran": fits}),
+          flush=True)
     return 0
 
 

@@ -42,13 +42,20 @@ def _model(config: str) -> dict:
 def test_every_cell_config_traffic_and_metric_loads_by_name():
     bench = spec.load_benchmark()
     assert {w["name"] for w in bench["workloads"]} == {
-        "kwt1-audio", "tiny-audio", "tiny-feature", "tiny-audio-256"}
+        "kwt1-audio", "tiny-audio", "tiny-feature", "tiny-audio-256",
+        "kwt1-float-audio", "internlm2-chat"}
     for w in bench["workloads"]:
         cell = spec.load_cell(w["name"])
-        assert cell.config["model"] and cell.config["frontend"]
-        assert cell.traffic["ingest"] in ("audio", "feature")
-        assert {m["name"] for m in cell.end_to_end} == {
-            "setup_s", "stream_capacity", "hop_p95_ms"}
+        assert cell.config["model"]
+        if cell.config.get("path", "stream") == "lm":
+            assert cell.traffic["prompt"] and cell.traffic["output"]
+            assert {m["name"] for m in cell.end_to_end} == {
+                "setup_s", "tokens_per_s", "ttft_p95_ms", "itl_p95_ms"}
+        else:
+            assert cell.config["frontend"]
+            assert cell.traffic["ingest"] in ("audio", "feature")
+            assert {m["name"] for m in cell.end_to_end} == {
+                "setup_s", "stream_capacity", "hop_p95_ms"}
         assert cell.per_layer
         for m in cell.per_layer:
             assert callable(spec.metric_reader(m["name"]))
@@ -56,7 +63,7 @@ def test_every_cell_config_traffic_and_metric_loads_by_name():
                  if m["name"] == "featurise_ms_per_step"
                  for c in m["workloads"]}
     assert featurise == {w["name"] for w in bench["workloads"]
-                         if spec.load_cell(w["name"]).traffic["ingest"]
+                         if spec.load_cell(w["name"]).traffic.get("ingest")
                          == "audio"}
 
 
@@ -215,8 +222,10 @@ def cpu_root(tmp_path_factory):
         "__pycache__", "tests"))
     for path in (root / "bench" / "configs").glob("*.json"):
         conf = json.loads(path.read_text())
-        conf["numerics"].update(attention_operands="float32",
-                                frontend_operands="float32")
+        for key in ("attention_operands", "frontend_operands",
+                    "linear_operands"):
+            if key in conf["numerics"]:
+                conf["numerics"][key] = "float32"
         path.write_text(json.dumps(conf))
     return root
 
@@ -242,18 +251,21 @@ def test_a_sound_run_is_correct_and_reports_its_metrics(cpu_root):
     assert checks["window_compiles"]["value"] == 0
 
 
-@pytest.mark.parametrize("workload", ["tiny-audio", "kwt1-audio"])
-def test_each_control_is_not_correct(workload):
+@pytest.mark.parametrize("workload,fail", [
+    ("tiny-audio", set(harness.CONTROLS)), ("kwt1-audio", {"grid"}),
+    ("kwt1-float-audio", {"grid", "attention"})])
+def test_each_control_is_not_correct(workload, fail):
     """The reference one precision step below the configuration's own
-    numerics, in the grids, the attention operands or the floats, in the
-    program's place: each fails a number the cell compares, or, where
-    none of them can tell it from the program, the 4-bit grid does."""
+    numerics, in the grids (or the float plan's linear operands), the
+    attention operands or the floats, in the program's place: each fails
+    a number the cell compares, or, where the program's own rounding
+    reads as far as a control's (KWT-1's 12 layers), the 4-bit grid and,
+    on the float plan, fp8 attention do (PERF.md section 2)."""
     result, checks = _run(ROOT, workload, control=True)
     assert set(result["control"]) == set(harness.CONTROLS)
     failed = {part for part, readings in result["control"].items()
               if _fails(readings, checks)}
-    assert failed == (set(harness.CONTROLS) if "flip_rate" in checks
-                      else {"grid"}), (result["control"], checks)
+    assert failed == fail, (result["control"], checks)
 
 
 def test_the_programs_own_4bit_weights_are_not_correct(cpu_root):
@@ -264,6 +276,13 @@ def test_the_programs_own_4bit_weights_are_not_correct(cpu_root):
 def test_a_sound_feature_ingest_run_is_correct(cpu_root):
     result, checks = _run(cpu_root, "tiny-feature")
     assert result["correct"], checks
+
+
+def test_a_sound_float_plan_run_is_correct(cpu_root):
+    result, checks = _run(cpu_root, "kwt1-float-audio")
+    assert result["correct"], checks
+    assert set(result["metrics"]) == {"setup_s", "stream_capacity",
+                                      "hop_p95_ms"}
 
 
 def _stale_state(orig):
@@ -292,7 +311,8 @@ def _altered_answer(orig):
     return hop
 
 
-@pytest.mark.parametrize("workload", ["tiny-audio", "kwt1-audio"])
+@pytest.mark.parametrize("workload", ["tiny-audio", "kwt1-audio",
+                                      "kwt1-float-audio"])
 @pytest.mark.parametrize("fault", [_stale_state, _half_batch,
                                    _altered_answer])
 def test_a_broken_timed_path_is_not_correct(monkeypatch, cpu_root, fault,

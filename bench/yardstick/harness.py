@@ -1,9 +1,13 @@
 """One run of one cell: set-up, the timed window, the check, the result.
 
-The system under test is the program's stream cell: weights go through
-``runtime.compile_model`` into a ``ServeCell`` whose ``StreamLanes`` serve
-every lane; each step hands ``StreamLanes.hop`` a fresh host chunk and
-takes back the detector events and logits on the host.
+A configuration names the serving path it runs on (``"path"``):
+``stream`` (the default) or ``lm``, which :mod:`yardstick.lm` serves.
+
+On the stream path the system under test is the program's stream cell:
+weights go through ``runtime.compile_model`` into a ``ServeCell`` whose
+``StreamLanes`` serve every lane; each step hands ``StreamLanes.hop`` a
+fresh host chunk and takes back the detector events and logits on the
+host.
 
 :func:`run` returns the result line and the numbers compared, each with
 its limit.  ``run.py`` prints them; the tests call :func:`run` with the
@@ -63,7 +67,8 @@ def check_device(chips: int):
 
 def _model_config(conf: dict):
     """The program's configuration for ``conf``, which has to agree with
-    every size the file states."""
+    every size the file states, and with its integer grid where it states
+    one."""
     from repro.configs import registry
     cfg = registry.get(conf["registry"]).config
     for key, want in conf["model"].items():
@@ -72,9 +77,11 @@ def _model_config(conf: dict):
         if have != want:
             raise ValueError(f"{conf['registry']}: {key} is {have!r} in the "
                              f"program, {want!r} in the configuration file")
+    num = conf["numerics"]
+    if num["weight_bits"] is None:
+        return cfg
     from repro.runtime.recipe import QuantRecipe
     recipe = QuantRecipe.from_config(cfg)
-    num = conf["numerics"]
     for key, have in (("weight_bits", recipe.bits),
                       ("weight_exponent", recipe.weight_exponent),
                       ("input_exponent", recipe.input_exponent)):
@@ -187,11 +194,13 @@ def logit_checks(served: np.ndarray, ref: np.ndarray) -> dict:
 def run(workload: str, seed: int, seconds: float, trace: bool, *,
         root: Path = spec.ROOT, lanes: int | None = None,
         require_chip: bool = True, control: bool = False,
-        program_bits: int | None = None, record: dict | None = None,
+        program_bits: int | None = None,
+        program_overrides: dict | None = None, record: dict | None = None,
         t_start: float | None = None, log=print) -> tuple[dict, dict]:
     """One run of ``workload``; returns ``(result, checks)``.
 
-    ``lanes`` overrides the mix's lane count (the lane sweep).
+    ``lanes`` overrides the mix's lane count (the lane sweep), or on the
+    LM path its slot count.
     ``control`` also computes the reference one precision step below the
     stated numerics in each part of :data:`CONTROLS` (the integer grids,
     the attention operands, the float intermediates), each put in the
@@ -203,20 +212,22 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     ``record``, a dict, receives the logits compared (``served``, ``ref``
     and, with ``control``, ``control.<part>``), the (lane, step) pairs
     they belong to and the weights.
+    On the LM path ``control`` computes the reference with its weights
+    one precision step lower, ``program_overrides`` sets keys of the
+    program's configuration apart from what the file states (a planted
+    fault), and ``record`` receives the requests compared and their gaps.
     """
     t_start = time.perf_counter() if t_start is None else t_start
     cell = spec.load_cell(workload, root)
     conf, mix = cell.config, cell.traffic
-    n_lanes = int(lanes or mix["lanes"])
+    path = conf.get("path", "stream")
+    if path not in ("stream", "lm"):
+        raise ValueError(f"unknown serving path {path!r}")
     import jax
     if require_chip:
         dev, count = check_device(cell.chips)
     else:
         dev, count = jax.devices()[0], len(jax.devices())
-    from repro import cell as cellmod
-    from repro import runtime
-    from repro.stream import detector, features
-    from yardstick import weights
 
     # one fixed directory inside the checkout, so that only a cell's first
     # run in a checkout compiles.  Unlike runtime.enable_compile_cache this
@@ -226,6 +237,18 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     jax.config.update("jax_compilation_cache_dir",
                       str(Path(root) / CACHE_DIR))
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    if path == "lm":
+        from yardstick import lm
+        return lm.run(cell, seed, seconds, trace, dev=dev, count=count,
+                      slots=lanes, control=control,
+                      program_overrides=program_overrides, record=record,
+                      t_start=t_start, log=log)
+
+    n_lanes = int(lanes or mix["lanes"])
+    from repro import cell as cellmod
+    from repro import runtime
+    from repro.stream import detector, features
+    from yardstick import weights
     counter = _CompileCounter()
     try:
         cfg = _model_config(conf)
@@ -268,17 +291,10 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
                 f"{t_traffic - t_model:.3f} s, joins of {n_lanes} lanes "
                 f"{join_s:.3f} s, {warm} warm-up steps "
                 f"{setup_s - (t_join - t_start) - join_s:.3f} s")
-            trace_dir = tempfile.mkdtemp(prefix="bench_trace_") \
-                if trace else None
-            if trace:
-                jax.profiler.start_trace(trace_dir)
-            try:
+            with profiled(trace) as trace_dir:
                 w = _timed_window(stream, traffic, warm,
                                   min(seconds, TRACE_SECONDS) if trace
                                   else seconds, keep_lanes, counter, trace)
-            finally:
-                if trace:
-                    jax.profiler.stop_trace()
             stats = dev.memory_stats() or {}
             mem_peak = int(stats.get("peak_bytes_in_use", 0))
             host_w = weights.to_host(params)
@@ -318,27 +334,78 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
               for name, v in readings.items() if name in limits}
     checks.update(window_compiles={"value": w.compiles, "limit": 0},
                   nonfinite_lane_hops={"value": w.nonfinite, "limit": 0})
-    correct = all(c["value"] <= c["limit"] for c in checks.values())
 
-    device = {"platform": dev.platform, "kind": dev.device_kind,
-              "count": count, "memory_peak_bytes": mem_peak}
-    result = {"correct": bool(correct), "attempted": int(lane_hops),
-              "failed": int(w.nonfinite)}
-    if not trace:
-        values = {
+    lows = {}
+    if control:
+        lows = {part: reference.kwt_logits(host_w, x, model,
+                                           stated.lowered(part))
+                for part in CONTROLS}
+    if record is not None:
+        record.update(served=served, ref=ref, weights=host_w,
+                      pairs=np.array([(keep_lanes[i], s) for i, s in pairs]),
+                      **{f"control.{p}": low for p, low in lows.items()})
+    red = reduce_trace(trace_dir) if trace else None
+    ctx = _reader_context(cell, conf, n_lanes, traffic.k, w, red,
+                          dev.device_kind) if trace else None
+    result = result_line(
+        cell, dev, count, mem_peak, checks, attempted=lane_hops,
+        failed=w.nonfinite,
+        values=None if trace else {
             "setup_s": setup_s,
             "stream_capacity": lane_hops * HOP_SECONDS / w.seconds,
             "hop_p95_ms": float(np.percentile(lat_ms, 95)),
-        }
+        },
+        red=red, ctx=ctx,
+        control={part: logit_checks(low, ref) for part, low in lows.items()}
+        if control else None)
+    return result, checks
+
+
+@contextlib.contextmanager
+def profiled(on: bool):
+    """The profiler around the timed window, when ``on``; yields the
+    directory its trace goes to (``None`` when off)."""
+    if not on:
+        yield None
+        return
+    import jax
+    trace_dir = tempfile.mkdtemp(prefix="bench_trace_")
+    jax.profiler.start_trace(trace_dir)
+    try:
+        yield trace_dir
+    finally:
+        jax.profiler.stop_trace()
+
+
+def reduce_trace(trace_dir: str):
+    """The traced window's reduction; the trace itself is deleted."""
+    red = trace_mod.reduce_dir(trace_dir)
+    shutil.rmtree(trace_dir, ignore_errors=True)
+    return red
+
+
+CONTAINER_OPS = ("while",)   # a loop's op spans the ops of its body
+
+
+def result_line(cell, dev, count: int, mem_peak: int, checks: dict, *,
+                attempted: int, failed: int, values: dict | None = None,
+                red=None, ctx: dict | None = None,
+                control: dict | None = None) -> dict:
+    """The run's result line, on either path.  An untraced run reports
+    ``values``, its end-to-end metrics, as far as the cell has them; a
+    traced one the per-layer metrics that the cell's readers find in
+    ``ctx`` (built on ``red``, the trace's reduction), the device's busy
+    seconds and the breakdown.  ``checks`` come last."""
+    result = {"correct": all(c["value"] <= c["limit"]
+                             for c in checks.values()),
+              "attempted": int(attempted), "failed": int(failed)}
+    device = {"platform": dev.platform, "kind": dev.device_kind,
+              "count": count, "memory_peak_bytes": mem_peak}
+    if red is None:
         units = {m["name"]: m["unit"] for m in cell.end_to_end}
         result["metrics"] = {k: {"value": v, "unit": units[k]}
                              for k, v in values.items() if k in units}
-        result["device"] = device
     else:
-        red = trace_mod.reduce_dir(trace_dir)
-        shutil.rmtree(trace_dir, ignore_errors=True)
-        ctx = _reader_context(cell, conf, n_lanes, traffic.k, w, red,
-                              device)
         metrics = {}
         for m in cell.per_layer:
             value = spec.metric_reader(m["name"], cell.bench)(ctx)
@@ -346,28 +413,22 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
                 metrics[m["name"]] = {"value": value, "unit": m["unit"]}
         result["metrics"] = metrics
         device.update(busy_s=red.busy_s, window_s=red.window_s)
-        result["device"] = device
-        result["breakdown"] = {"device_ops": red.top_ops(10),
+    result["device"] = device
+    if red is not None:
+        ops = [op for op in red.top_ops(len(red.ops))
+               if op[0].rsplit("/", 1)[-1] not in CONTAINER_OPS]
+        result["breakdown"] = {"device_ops": ops[:10],
                                "idle_gaps": red.idle_by_span(10)}
-    lows = {}
-    if control:
-        lows = {part: reference.kwt_logits(host_w, x, model,
-                                           stated.lowered(part))
-                for part in CONTROLS}
-        result["control"] = {part: logit_checks(low, ref)
-                             for part, low in lows.items()}
-    if record is not None:
-        record.update(served=served, ref=ref, weights=host_w,
-                      pairs=np.array([(keep_lanes[i], s) for i, s in pairs]),
-                      **{f"control.{p}": low for p, low in lows.items()})
+    if control is not None:
+        result["control"] = control
     result["checks"] = checks
-    return result, checks
+    return result
 
 
-def _reader_context(cell, conf, n_lanes, k, w, red, device) -> dict:
+def _reader_context(cell, conf, n_lanes, k, w, red, kind: str) -> dict:
     from yardstick import peaks
     model = conf["model"]
-    chip = peaks.peaks(device["kind"])
+    chip = peaks.peaks(kind)
     steps = w.steps
     return {
         "cell": cell.name, "lanes": n_lanes, "chunk_hops": k,
@@ -376,7 +437,8 @@ def _reader_context(cell, conf, n_lanes, k, w, red, device) -> dict:
         "step_flops": work.step_flops(model, k),
         "encoder_flops": work.encoder_flops(model),
         "encoder_min_bytes": work.encoder_min_bytes(
-            model, n_lanes, conf["numerics"]["weight_bits"]),
+            model, n_lanes, conf["numerics"]["weight_bits"]
+            or conf["numerics"]["stored_weight_bits"]),
         "peak_ops_per_s": chip[conf["numerics"]["peak"]],
         "hbm_bytes_per_s": chip["hbm_bytes_per_s"],
     }

@@ -20,7 +20,9 @@ states (its ``numerics`` block, read by :func:`Numerics.stated`):
   rounded, half up, to a ``bits``-wide two's-complement grid of step
   ``2**-weight_exponent`` or ``2**-input_exponent``, each matmul's
   accumulator, in units of the product of the two steps, clipped to
-  ``acc_bits``;
+  ``acc_bits``; or, where the configuration states no grid
+  (``weight_bits`` null: the float plan), each linear layer's operands
+  rounded to ``linear_operands`` and the products summed exactly;
 * the operands of the two attention matmuls (queries and keys; weights
   and values) rounded to ``attention_operands``, the products summed
   exactly;
@@ -112,10 +114,11 @@ def mfcc(audio: np.ndarray, fr: dict,
 class Numerics:
     """What a configuration states about how the model computes."""
 
-    bits: int
-    weight_exponent: int
-    input_exponent: int
-    acc_bits: int
+    bits: int | None                 # None: no integer grid
+    weight_exponent: int | None
+    input_exponent: int | None
+    acc_bits: int | None
+    linear_operands: str | None      # a dtype name, where there is no grid
     attention_operands: str          # a dtype name, e.g. ``bfloat16``
     float_dtype: str                 # ``float32``, or lower in a control
     softmax_lut: dict | None         # None: exact softmax
@@ -125,9 +128,10 @@ class Numerics:
     def stated(cls, num: dict) -> "Numerics":
         """From a configuration file's ``numerics`` block."""
         return cls(bits=num["weight_bits"],
-                   weight_exponent=num["weight_exponent"],
-                   input_exponent=num["input_exponent"],
-                   acc_bits=num["acc_bits"],
+                   weight_exponent=num.get("weight_exponent"),
+                   input_exponent=num.get("input_exponent"),
+                   acc_bits=num.get("acc_bits"),
+                   linear_operands=num.get("linear_operands"),
                    attention_operands=num["attention_operands"],
                    float_dtype=num["float_dtype"],
                    softmax_lut=num.get("softmax_lut"),
@@ -135,8 +139,12 @@ class Numerics:
 
     def lowered(self, part: str) -> "Numerics":
         """One step below what is stated, in ``part``: ``grid`` (int8 to
-        int4 with the same ranges), ``attention`` (bfloat16 operands to
-        float8 e4m3) or ``float`` (float32 to bfloat16)."""
+        int4 with the same ranges; with no grid, the linear layers'
+        operands, bfloat16 to float8 e4m3), ``attention`` (bfloat16
+        operands to float8 e4m3) or ``float`` (float32 to bfloat16)."""
+        if part == "grid" and self.bits is None:
+            return dataclasses.replace(
+                self, linear_operands=LOWER[self.linear_operands])
         if part == "grid":
             cut = self.bits - 4
             return dataclasses.replace(
@@ -172,6 +180,9 @@ def _grid(x, bits, exp):
 
 
 def _linear(x, w, b, num: Numerics):
+    if num.bits is None:
+        return round_to(x, num.linear_operands) \
+            @ round_to(w, num.linear_operands) + b
     acc = _grid(x, num.bits, num.input_exponent) \
         @ _grid(w, num.bits, num.weight_exponent)
     lim = 2 ** (num.acc_bits - 1)
@@ -268,7 +279,8 @@ def kwt_logits(w: dict, frames: np.ndarray, model: dict,
                    num))
     p = x.shape[0]
     cls = np.broadcast_to(w["cls"], (p, 1, x.shape[-1]))
-    pos = _grid(w["pos"], num.bits, num.weight_exponent) \
+    pos = w["pos"] if num.bits is None else \
+        _grid(w["pos"], num.bits, num.weight_exponent) \
         * 2.0 ** -num.weight_exponent
     x = fl(np.concatenate([cls, x], axis=1) + pos)
     s = x.shape[1]

@@ -11,7 +11,15 @@ metrics read.
   program's ``jax.named_scope`` names); the rest, such as the copies of
   lane state and ops that carry no name, counts as ``other``.
 * Each idle gap of the device is labelled by the harness span
-  (``prepare_chunk``, ``hop``) the host was in at its middle.
+  (``prepare_chunk``, ``hop``, ``lm_step``, ``submit``) the host was in
+  at its middle.
+* Program runs are the device planes' ``XLA Modules`` events, named
+  ``<jitted function>(<fingerprint>)``: one name for each compiled
+  program.  A program's device time is the union of the intervals of the
+  ops that start in its runs (a loop's op spans the ops of its body, so
+  a plain sum would count them twice), and each name gets its runs that
+  start in the window counted, so that a reader can tell a program that
+  runs every step from one that runs on some.
 """
 
 from __future__ import annotations
@@ -24,9 +32,10 @@ import os
 import numpy as np
 
 SCOPES = ("featurise", "embed", "encode")
-HOST_SPANS = ("prepare_chunk", "hop")
+HOST_SPANS = ("prepare_chunk", "hop", "lm_step", "submit")
 WINDOW_SPAN = "bench_window"
 OPS_LINE = "XLA Ops"
+MODULES_LINE = "XLA Modules"
 
 
 @dataclasses.dataclass
@@ -37,6 +46,10 @@ class Reduction:
     ops: dict                 # op name -> device seconds
     gaps: dict                # host span -> idle device seconds
     devices: int
+    module_s: dict = dataclasses.field(default_factory=dict)
+    #                           program name -> device seconds of its ops
+    module_runs: dict = dataclasses.field(default_factory=dict)
+    #                           program name -> runs started in the window
 
     def top_ops(self, n: int) -> list:
         return [[k, float(v)] for k, v in
@@ -85,7 +98,7 @@ def reduce_file(path: str) -> Reduction:
     pd = ProfileData.from_file(path)
     op_path = xplane.op_names(path)
     window, spans = None, []
-    device_ops = []
+    device_ops, device_runs = [], []
     for plane in pd.planes:
         if plane.name.startswith("/host:"):
             for line in plane.lines:
@@ -96,15 +109,18 @@ def reduce_file(path: str) -> Reduction:
                         spans.append((ev.start_ns, ev.start_ns
                                       + ev.duration_ns, ev.name))
         elif plane.name.startswith("/device:"):
-            evs = []
+            evs, runs = [], []
             for line in plane.lines:
-                if line.name != OPS_LINE:
-                    continue
-                for ev in line.events:
-                    evs.append((ev.start_ns, ev.start_ns + ev.duration_ns,
-                                ev.name, op_path.get(ev.name, "")))
+                if line.name == OPS_LINE:
+                    for ev in line.events:
+                        evs.append((ev.start_ns, ev.start_ns
+                                    + ev.duration_ns, ev.name,
+                                    op_path.get(ev.name, "")))
+                elif line.name == MODULES_LINE:
+                    runs.extend((ev.start_ns, ev.name) for ev in line.events)
             if evs:
                 device_ops.append(evs)
+                device_runs.append(sorted(runs))
     if window is None:
         raise ValueError(f"{path}: no {WINDOW_SPAN!r} span in the trace")
     w0, w1 = window
@@ -112,9 +128,13 @@ def reduce_file(path: str) -> Reduction:
     span_starts = np.asarray([s[0] for s in spans], np.float64)
     busy, scope_s = 0.0, collections.Counter()
     ops, gaps = collections.Counter(), collections.Counter()
-    for evs in device_ops:
-        iv = []
+    module_s, module_runs = collections.Counter(), collections.Counter()
+    for evs, runs in zip(device_ops, device_runs):
+        run_starts = np.asarray([r[0] for r in runs], np.float64)
+        module_runs.update(name for start, name in runs if w0 <= start < w1)
+        iv, run_iv = [], collections.defaultdict(list)
         for s, e, name, path in evs:
+            r = int(np.searchsorted(run_starts, s, side="right")) - 1
             s, e = max(s, w0), min(e, w1)
             if e <= s:
                 continue
@@ -123,6 +143,11 @@ def reduce_file(path: str) -> Reduction:
             scope = scope_of(path)
             scope_s[scope] += sec
             ops[f"{scope}/{short_name(name)}"] += sec
+            if r >= 0:
+                run_iv[r].append((s, e))
+        for r, run in run_iv.items():
+            u = _union(np.asarray(run, np.float64))
+            module_s[runs[r][1]] += float(np.sum(u[:, 1] - u[:, 0])) * 1e-9
         merged = _union(np.asarray(iv, np.float64))
         busy += float(np.sum(merged[:, 1] - merged[:, 0])) * 1e-9
         edges = np.concatenate([[w0], merged.reshape(-1), [w1]])
@@ -138,7 +163,9 @@ def reduce_file(path: str) -> Reduction:
                      scope_s={k: v / n for k, v in scope_s.items()},
                      ops={k: v / n for k, v in ops.items()},
                      gaps={k: v / n for k, v in gaps.items()},
-                     devices=len(device_ops))
+                     devices=len(device_ops),
+                     module_s={k: v / n for k, v in module_s.items()},
+                     module_runs={k: v / n for k, v in module_runs.items()})
 
 
 def reduce_dir(trace_dir: str) -> Reduction:
