@@ -7,7 +7,8 @@ shapes), so a plan that stops reading padded cache cannot pass 100 %."""
 
 def read(ctx):
     ph = ctx["phases"]
-    flops, nbytes = ctx["decode_flops"], ctx["decode_min_bytes"]
+    work = ctx["work"]
+    flops, nbytes = work["decode_flops"], work["decode_min_bytes"]
     if ph is None or ph["decode"] <= 0 or not flops:
         return None
     least = sum(max(f / ctx["peak_flops_per_s"], b / ctx["hbm_bytes_per_s"])

@@ -9,7 +9,8 @@ def read(ctx):
     t = ctx["trace"].scope_s.get("encode", 0.0)
     if t <= 0 or ctx["steps"] == 0:
         return None
+    work = ctx["work"]
     per_step = max(
-        ctx["encoder_flops"] * ctx["lanes"] / ctx["peak_ops_per_s"],
-        ctx["encoder_min_bytes"] / ctx["hbm_bytes_per_s"])
+        work["encoder_flops"] * ctx["lanes"] / ctx["peak_ops_per_s"],
+        work["encoder_min_bytes"] / ctx["hbm_bytes_per_s"])
     return 100.0 * per_step * ctx["steps"] / t

@@ -1,0 +1,11 @@
+"""Host time of the ``copy_in`` phase of ``StreamLanes.hop`` (the host
+chunk to the device) per window step: the program's ``cell.hop.copy_in``
+span, clipped to the traced window, over the window's steps.  A trace
+without the program's spans has nothing to read."""
+
+
+def read(ctx):
+    t = ctx["trace"].program_s.get("cell.hop.copy_in", 0.0)
+    if t <= 0 or ctx["steps"] == 0:
+        return None
+    return 1e3 * t / ctx["steps"]

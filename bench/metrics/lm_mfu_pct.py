@@ -7,7 +7,7 @@ configuration's peak and the traced window."""
 
 def read(ctx):
     red = ctx["trace"]
-    if ctx["model_flops"] == 0 or red.window_s <= 0:
+    flops = ctx["work"]["model_flops"]
+    if flops == 0 or red.window_s <= 0:
         return None
-    return 100.0 * ctx["model_flops"] / ctx["peak_flops_per_s"] \
-        / red.window_s
+    return 100.0 * flops / ctx["peak_flops_per_s"] / red.window_s

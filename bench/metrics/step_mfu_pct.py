@@ -7,6 +7,7 @@ def read(ctx):
     red = ctx["trace"]
     if ctx["lane_hops"] == 0 or red.window_s <= 0:
         return None
-    ops_per_s = ctx["step_flops"] * ctx["lane_hops"] / ctx["chunk_hops"] \
+    work = ctx["work"]
+    ops_per_s = work["step_flops"] * ctx["lane_hops"] / ctx["chunk_hops"] \
         / red.window_s
     return 100.0 * ops_per_s / ctx["peak_ops_per_s"]

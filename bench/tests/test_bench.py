@@ -25,7 +25,8 @@ BENCH = Path(__file__).resolve().parents[1]
 ROOT = BENCH.parent
 sys.path.insert(0, str(BENCH))
 
-from yardstick import harness, peaks, reference, spec, trace, work  # noqa: E402
+from yardstick import (harness, peaks, reference, spans, spec,  # noqa: E402
+                       trace, work)
 
 TRACE = BENCH / "tests" / "data" / "tiny_audio_64lanes.xplane.pb"
 SEED = 2 ** 33 + 7          # wider than 32 bits, as a run's seed may be
@@ -323,6 +324,33 @@ def test_a_broken_timed_path_is_not_correct(monkeypatch, cpu_root, fault,
     result, checks = _run(cpu_root, workload)
     assert not result["correct"], checks
     assert checks["logit_error"]["value"] > checks["logit_error"]["limit"]
+
+
+def test_a_traced_run_gives_its_readers_spans_counters_and_work(
+        cpu_root, reader_contexts, v5e_peaks):
+    """On the CPU too the profiler records the program's ``cell.hop``
+    spans: a traced run hands its readers each phase's host seconds, the
+    increase of the program's counters over the window and the work
+    terms, and the four phase metrics together take no more than a
+    step."""
+    result, checks = harness.run("tiny-audio", SEED, 0.5, True,
+                                 lanes=TEST_LANES, require_chip=False,
+                                 root=cpu_root, log=lambda s: None)
+    assert result["correct"], checks
+    ctx = reader_contexts[0]
+    red, steps, counted = ctx["trace"], ctx["steps"], ctx["counters"]
+    assert set(red.program_s) == {spans.HOP_SPAN, *(
+        f"{spans.HOP_SPAN}.{p}" for p in spans.PHASES)}
+    assert counted["cell_hops_total"] == steps * TEST_LANES > 0
+    assert counted["cell_hop_fetches_total"] == steps
+    assert counted["cell_tokens_total"] == 0
+    for p in spans.PHASES:
+        assert counted[f'cell_hop_phase_seconds_total{{phase="{p}"}}'] > 0
+    assert set(ctx["work"]) == {"step_flops", "encoder_flops",
+                                "encoder_min_bytes"}
+    phases = [result["metrics"][f"hop_{p}_ms"]["value"]
+              for p in spans.PHASES]
+    assert 0 < sum(phases) <= 1e3 * red.window_s / steps
 
 
 # -- the entry point ---------------------------------------------------------------

@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import dataclasses
 import json
+import re
 import shutil
 import sys
 import types
@@ -30,7 +31,9 @@ ROOT = BENCH.parent
 sys.path.insert(0, str(BENCH))
 
 from yardstick import (harness, lm, lm_reference, lm_traffic,  # noqa: E402
-                       lm_weights, reference, spec, trace, weights, work)
+                       lm_weights, reference, spec, trace, weights)
+
+dense = spec.architecture("dense")
 
 SEED = 2 ** 33 + 11
 LM_CELL = "internlm2-chat"
@@ -180,27 +183,27 @@ def test_lm_flop_counts_equal_the_hand_counts():
     m = dict(n_layers=2, d_model=8, n_heads=4, n_kv_heads=2, head_dim=2,
              d_ff=16, vocab_size=10)
     # per layer: q 8x8, k and v 8x4, out 8x8, gate/up/down 8x16
-    assert work.lm_layer_weights(m) == 64 + 2 * 32 + 64 + 3 * 128
+    assert dense.layer_weights(m) == 64 + 2 * 32 + 64 + 3 * 128
     one = 2 * 2 * 576                                   # two layers' matmuls
     # a token at position 3 attends to 4 keys: 2 * 2 * 4 * 8 per layer
-    assert work.lm_tokens_flops(m, 3, 1, head=False) == one + 2 * 128
-    assert work.lm_tokens_flops(m, 3, 1, head=True) == \
+    assert dense.tokens_flops(m, 3, 1, head=False) == one + 2 * 128
+    assert dense.tokens_flops(m, 3, 1, head=True) == \
         one + 2 * 128 + 2 * 8 * 10
     # five prompt tokens at positions 0..4 attend to 1+2+3+4+5 keys
-    assert work.lm_tokens_flops(m, 0, 5, head=False) == \
+    assert dense.tokens_flops(m, 0, 5, head=False) == \
         5 * one + 2 * 2 * 2 * 15 * 8
-    assert work.lm_tokens_flops(m, 0, 5, head=False) == sum(
-        work.lm_tokens_flops(m, p, 1, head=False) for p in range(5))
+    assert dense.tokens_flops(m, 0, 5, head=False) == sum(
+        dense.tokens_flops(m, p, 1, head=False) for p in range(5))
 
 
 def test_lm_least_bytes_count_valid_positions_only():
     m = dict(n_layers=2, d_model=8, n_heads=4, n_kv_heads=2, head_dim=2,
              d_ff=16, vocab_size=10)
     weights_b = (2 * 576 + 80) * 2 + 5 * 8 * 4
-    assert work.lm_weight_bytes(m, 2, 4) == weights_b
+    assert dense.weight_read_bytes(m, 2, 4) == weights_b
     kv_token = 2 * 2 * 4 * 2                  # layers x (K, V) x 4 x bf16
     lane = kv_token + 8 * 2 + 10 * 2          # new K/V, embed row, logits
-    assert work.lm_decode_min_bytes(m, [3, 7], 2, 2, 4) == \
+    assert dense.decode_min_bytes(m, [3, 7], 2, 2, 4) == \
         weights_b + kv_token * 10 + 2 * lane
     # the same requests counted under a cache four times as long: the
     # counts are of real positions, not of the cache's length
@@ -211,16 +214,16 @@ def test_lm_least_bytes_count_valid_positions_only():
         bank = lm_traffic.RequestBank(dict(mix, max_len=max_len), 5, 92544)
         log = lm.Log(steps={0: [3, 4, 5], 1: [4, 5], ("warm", 0): [0]},
                      window=range(3, 6))
-        counts.append(lm._window_work(log, bank, conf["model"],
+        counts.append(lm._window_work(dense, log, bank, conf["model"],
                                       conf["numerics"]))
     assert counts[0] == counts[1]
     prefill, decode, nbytes = counts[0]
     bank = lm_traffic.RequestBank(mix, 5, 92544)
     n0, n1 = (len(bank.request(i)[0]) for i in (0, 1))
-    assert prefill == sum(work.lm_tokens_flops(conf["model"], 0, n - 1,
+    assert prefill == sum(dense.tokens_flops(conf["model"], 0, n - 1,
                                                False) for n in (n0, n1))
-    assert decode[0] == work.lm_tokens_flops(conf["model"], n0 - 1, 1, True)
-    assert nbytes[2] == work.lm_decode_min_bytes(
+    assert decode[0] == dense.tokens_flops(conf["model"], n0 - 1, 1, True)
+    assert nbytes[2] == dense.decode_min_bytes(
         conf["model"], [n0 + 1, n1], 2, 2, 4)
 
 
@@ -307,7 +310,7 @@ def test_lm_reference_agrees_with_prefill_and_cached_decode():
     model = dict(conf["model"], **SMOKE, dtype="float32")
     num = dict(conf["numerics"], weights="float32", activations="float32")
     cfg = registry.get("internlm2-1.8b").smoke.with_(rope_theta=1e6)
-    params = lm_weights.make(SEED, model, num, 0.1)
+    params = lm_weights.make(SEED, dense.shapes(model), num, 0.1)
     eng = runtime.compile_model(cfg, params, backend="float")
     rng = np.random.default_rng(0)
     seq = rng.integers(0, 256, (2, 24), dtype=np.int32)
@@ -317,14 +320,13 @@ def test_lm_reference_agrees_with_prefill_and_cached_decode():
     for t in range(16, 24):
         out, state = eng.decode_step(jnp.asarray(seq[:, t]), state)
         served.append(np.asarray(out))
-    ref = lm_reference.logits(params, seq, model,
-                              lm_reference.Numerics.stated(num), "float32")
+    ref = dense.logits(params, seq, model,
+                       lm_reference.Numerics.stated(num), "float32")
     np.testing.assert_allclose(np.stack(served, 1), ref[:, 15:],
                                rtol=2e-4, atol=2e-4)
     with pytest.raises(AssertionError):
-        wrong = lm_reference.logits(params, seq, dict(model, rope_theta=1e4),
-                                    lm_reference.Numerics.stated(num),
-                                    "float32")
+        wrong = dense.logits(params, seq, dict(model, rope_theta=1e4),
+                             lm_reference.Numerics.stated(num), "float32")
         np.testing.assert_allclose(np.stack(served, 1), wrong[:, 15:],
                                    rtol=2e-4, atol=2e-4)
 
@@ -338,7 +340,7 @@ def test_the_lm_weights_are_in_the_programs_layout():
     theirs = jax.eval_shape(lambda k: transformer.init_params(cfg, k),
                             jax.random.PRNGKey(0))
     mine = jax.eval_shape(lambda: lm_weights.make(
-        SEED, conf["model"], conf["numerics"], 0.02))
+        SEED, dense.shapes(conf["model"]), conf["numerics"], 0.02))
     lm_weights.check_layout(mine, theirs)
     total = sum(x.size for x in jax.tree.leaves(mine))
     assert 1.88e9 < total < 1.90e9                       # ~1.89 B parameters
@@ -425,3 +427,173 @@ def test_a_broken_lm_timed_path_is_not_correct(monkeypatch, lm_smoke, fault):
     result, checks = _lm_run(lm_smoke, **kw)
     assert not result["correct"], checks
     assert checks["token_gap"]["value"] > checks["token_gap"]["limit"]
+
+
+# -- the dense architecture reads as it did before it was a module ------------
+
+PINNED = json.loads((BENCH / "tests" / "data" / "pinned_readings.json")
+                    .read_text())
+
+
+def _pinned_weights():
+    conf = _conf("internlm2-1.8b-bf16")
+    model = dict(conf["model"], **SMOKE)
+    pin = PINNED["weights"]
+    return model, conf["numerics"], lm_weights.make(
+        pin["seed"], dense.shapes(model), conf["numerics"], pin["init_std"])
+
+
+def test_the_dense_weights_are_the_pinned_ones_bit_for_bit():
+    """InternLM2's weights at the smoke size, drawn through the
+    architecture module's layout, hash as the harness drew them before
+    (``data/pinned_readings.json``)."""
+    import hashlib
+
+    import jax
+    _, _, params = _pinned_weights()
+    got = {jax.tree_util.keystr(path): hashlib.sha256(
+        np.asarray(leaf).tobytes()).hexdigest()
+        for path, leaf in jax.tree_util.tree_flatten_with_path(params)[0]}
+    assert got == PINNED["weights"]["sha256"]
+
+
+def test_the_dense_reference_is_the_pinned_one_bit_for_bit():
+    """On those weights the reference's logits at the stated numerics,
+    and the control's best, top and picked logits, equal those recorded
+    before (``data/pinned_reference.npz``, XLA:CPU)."""
+    model, num, params = _pinned_weights()
+    pin = np.load(BENCH / "tests" / "data" / "pinned_reference.npz")
+    seq = pin["tokens"]
+    stated = lm_reference.Numerics.stated(num)
+    np.testing.assert_array_equal(
+        dense.logits(params, seq, model, stated, "bfloat16"), pin["logits"])
+    best, top, picked = dense.evaluate(
+        params, seq, np.stack([seq, np.roll(seq, 1, -1)]), model,
+        stated.lowered(), "bfloat16")
+    np.testing.assert_array_equal(best, pin["low_best"])
+    np.testing.assert_array_equal(top, pin["low_top"])
+    np.testing.assert_array_equal(picked, pin["low_picked"])
+
+
+# -- an architecture brought by files alone -----------------------------------
+
+RECORDING = '''"""A test architecture: the dense one, recording each call."""
+
+from pathlib import Path
+
+from yardstick import spec
+
+dense = spec.architecture("dense", Path(__file__).resolve().parents[1])
+calls = []
+
+
+def _recorded(name):
+    fn = getattr(dense, name)
+
+    def call(*args, **kw):
+        calls.append(name)
+        return fn(*args, **kw)
+    return call
+
+
+shapes = _recorded("shapes")
+evaluate = _recorded("evaluate")
+logits = _recorded("logits")
+tokens_flops = _recorded("tokens_flops")
+decode_min_bytes = _recorded("decode_min_bytes")
+
+
+def window_work(log, bank, model, numerics):
+    calls.append("window_work")
+    return {"served_tokens": sum(
+        1 for steps in log.steps.values() for k in steps if k in log.window)}
+'''
+
+SERVED_TOKENS = '''def read(ctx):
+    return ctx["work"]["served_tokens"] or None
+'''
+
+
+@pytest.fixture
+def arch_root(lm_smoke, tmp_path):
+    """``lm_smoke``, with files and entries added and none changed: an
+    architecture module ``recording``, a configuration that names it, a
+    cell of that configuration on ``internlm2-chat``'s mix, and a
+    per-layer metric that reads a term of the module's ``window_work``."""
+    root = tmp_path / "arch"
+    shutil.copytree(lm_smoke, root,
+                    ignore=shutil.ignore_patterns(harness.CACHE_DIR))
+    bench = root / "bench"
+    (bench / "arch" / "recording.py").write_text(RECORDING)
+    (bench / "metrics" / "served_tokens.py").write_text(SERVED_TOKENS)
+    conf = json.loads((bench / "configs" / "internlm2-1.8b-bf16.json")
+                      .read_text())
+    conf["architecture"] = "recording"
+    (bench / "configs" / "recording-lm.json").write_text(json.dumps(conf))
+    spec_ = json.loads((root / "BENCHMARK.json").read_text())
+    spec_["configs"].append({"name": "recording-lm", "source": "test",
+                             "file": "bench/configs/recording-lm.json",
+                             "reduced": [], "why": "test"})
+    spec_["workloads"].append({"name": "recording-chat",
+                               "config": "recording-lm",
+                               "traffic": "internlm2-chat", "chips": 1,
+                               "why": "test"})
+    for m in spec_["end_to_end"]:
+        if LM_CELL in m.get("workloads", ()):
+            m["workloads"].append("recording-chat")
+    spec_["per_layer"].append({"name": "served_tokens", "unit": "tokens",
+                               "better": "higher", "source": "host_clock",
+                               "layer": "scheduler", "moves": "tokens_per_s",
+                               "workloads": ["recording-chat"]})
+    (root / "BENCHMARK.json").write_text(json.dumps(spec_))
+    return root
+
+
+def test_a_configuration_runs_through_the_architecture_it_names(
+        arch_root, reader_contexts, v5e_peaks):
+    """Weights, the reference, the FLOP and byte counts and the extra work
+    terms of a traced run all come from the module the configuration
+    names, and a metric added as a file reads its own term; the readers
+    also get the window's counters and the trace's named and program
+    time."""
+    result, checks = harness.run("recording-chat", SEED, 1.0, True,
+                                 require_chip=False, root=arch_root,
+                                 log=lambda s: None)
+    assert result["correct"], checks
+    calls = set(spec.architecture("recording", arch_root / "bench").calls)
+    assert {"shapes", "evaluate", "tokens_flops", "decode_min_bytes",
+            "window_work"} <= calls
+    ctx = reader_contexts[0]
+    served = ctx["work"]["served_tokens"]
+    # the closed loop keeps every slot busy, the one whose request under
+    # way ended in set-up's step too: a token per slot and step
+    slots = spec.load_traffic(LM_CELL, arch_root / "bench")["slots"]
+    assert served == slots * ctx["steps"] > 0
+    assert result["metrics"]["served_tokens"]["value"] == served
+    assert {"model_flops", "decode_flops", "decode_min_bytes"} <= set(
+        ctx["work"])
+    # the program counted the tokens the harness saw served in the window
+    assert ctx["counters"]["cell_tokens_total"] == served
+    assert ctx["counters"]["cell_hops_total"] == 0
+    assert isinstance(ctx["trace"].named_s, dict)
+    assert isinstance(ctx["trace"].program_s, dict)
+
+
+@pytest.mark.parametrize("name,error", [("no-such-model", FileNotFoundError),
+                                        (None, ValueError)])
+def test_an_lm_configuration_without_its_architecture_fails_at_load(
+        tmp_path, name, error):
+    shutil.copy(ROOT / "BENCHMARK.json", tmp_path)
+    shutil.copytree(BENCH, tmp_path / "bench", ignore=shutil.ignore_patterns(
+        "__pycache__", "tests"))
+    path = tmp_path / "bench" / "configs" / "internlm2-1.8b-bf16.json"
+    conf = json.loads(path.read_text())
+    if name is None:
+        del conf["architecture"]
+    else:
+        conf["architecture"] = name
+    path.write_text(json.dumps(conf))
+    want = str(tmp_path / "bench" / "arch" / "no-such-model.py") \
+        if name else "architecture"
+    with pytest.raises(error, match=re.escape(want)):
+        spec.load_cell(LM_CELL, tmp_path)

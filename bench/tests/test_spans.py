@@ -60,19 +60,18 @@ def test_phases_cover_cell_hop_and_cell_hop_covers_the_harness_hop(steps):
 
 def test_phase_means_per_step(steps):
     """The per-step means a phase metric reads are the window's phase
-    time over its steps: here every step's span lies inside the window."""
-    per_step = spans.phase_ms_per_step(SPANS, len(steps))
-    assert set(per_step) == set(spans.PHASES)
+    time (the reduction's ``program_s``) over its steps: here every
+    step's span lies inside the window."""
+    sec = trace.reduce_file(SPANS).program_s
     for p in spans.PHASES:
         want = statistics.fmean(1e-6 * st.phase_ns(p) for st in steps)
-        assert per_step[p] == pytest.approx(want, rel=1e-9)
-    sec = spans.program_seconds(SPANS)
+        got = 1e3 * sec[f"{spans.HOP_SPAN}.{p}"] / len(steps)
+        assert got == pytest.approx(want, rel=1e-9)
     assert sum(sec[c] for c in CHILDREN) <= sec[spans.HOP_SPAN]
 
 
 def test_a_trace_without_program_spans_reads_nothing():
-    assert spans.program_seconds(OLD) == {}
-    assert spans.phase_ms_per_step(OLD, 8) is None
+    assert trace.reduce_file(OLD).program_s == {}
     assert all(st.program == {} for st in spans.steps(OLD))
     assert all(spans.clock_offsets_ns(st) is None
                for st in spans.steps(OLD))

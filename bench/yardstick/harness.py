@@ -291,10 +291,12 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
                 f"{t_traffic - t_model:.3f} s, joins of {n_lanes} lanes "
                 f"{join_s:.3f} s, {warm} warm-up steps "
                 f"{setup_s - (t_join - t_start) - join_s:.3f} s")
+            before = counters()
             with profiled(trace) as trace_dir:
                 w = _timed_window(stream, traffic, warm,
                                   min(seconds, TRACE_SECONDS) if trace
                                   else seconds, keep_lanes, counter, trace)
+            counted = counter_increase(before, counters())
             stats = dev.memory_stats() or {}
             mem_peak = int(stats.get("peak_bytes_in_use", 0))
             host_w = weights.to_host(params)
@@ -346,7 +348,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
                       **{f"control.{p}": low for p, low in lows.items()})
     red = reduce_trace(trace_dir) if trace else None
     ctx = _reader_context(cell, conf, n_lanes, traffic.k, w, red,
-                          dev.device_kind) if trace else None
+                          dev.device_kind, counted) if trace else None
     result = result_line(
         cell, dev, count, mem_peak, checks, attempted=lane_hops,
         failed=w.nonfinite,
@@ -378,10 +380,32 @@ def profiled(on: bool):
 
 
 def reduce_trace(trace_dir: str):
-    """The traced window's reduction; the trace itself is deleted."""
+    """The traced window's reduction, the program's spans and named scopes
+    with it; the trace itself is deleted."""
     red = trace_mod.reduce_dir(trace_dir)
     shutil.rmtree(trace_dir, ignore_errors=True)
     return red
+
+
+def counters() -> dict:
+    """Every counter on the program's telemetry registry, the one a
+    ``ServeCell`` writes to, by name and labels (``name`` or
+    ``name{key="value",...}``, the labels sorted)."""
+    from repro import telemetry
+    from repro.telemetry.metrics import Counter
+    out = {}
+    for m in telemetry.default_registry().metrics():
+        if isinstance(m, Counter):
+            labels = ",".join(f'{k}="{v}"'
+                              for k, v in sorted((m.labels or {}).items()))
+            out[f"{m.name}{{{labels}}}" if labels else m.name] = m.value
+    return out
+
+
+def counter_increase(before: dict, after: dict) -> dict:
+    """Each counter's increase from ``before`` to ``after``
+    (:func:`counters`)."""
+    return {k: v - before.get(k, 0.0) for k, v in after.items()}
 
 
 CONTAINER_OPS = ("while",)   # a loop's op spans the ops of its body
@@ -425,7 +449,12 @@ def result_line(cell, dev, count: int, mem_peak: int, checks: dict, *,
     return result
 
 
-def _reader_context(cell, conf, n_lanes, k, w, red, kind: str) -> dict:
+def _reader_context(cell, conf, n_lanes, k, w, red, kind: str,
+                    counters: dict) -> dict:
+    """What the per-layer readers of a traced stream run read: the trace's
+    reduction (``trace``), ``counters`` (the increase of each program
+    counter over the window) and under ``work`` the work of a lane-hop
+    and of an encoder step, counted from shapes."""
     from yardstick import peaks
     model = conf["model"]
     chip = peaks.peaks(kind)
@@ -433,12 +462,14 @@ def _reader_context(cell, conf, n_lanes, k, w, red, kind: str) -> dict:
     return {
         "cell": cell.name, "lanes": n_lanes, "chunk_hops": k,
         "steps": steps, "lane_hops": steps * n_lanes * k,
-        "trace": red,
-        "step_flops": work.step_flops(model, k),
-        "encoder_flops": work.encoder_flops(model),
-        "encoder_min_bytes": work.encoder_min_bytes(
-            model, n_lanes, conf["numerics"]["weight_bits"]
-            or conf["numerics"]["stored_weight_bits"]),
+        "trace": red, "counters": counters,
+        "work": {
+            "step_flops": work.step_flops(model, k),
+            "encoder_flops": work.encoder_flops(model),
+            "encoder_min_bytes": work.encoder_min_bytes(
+                model, n_lanes, conf["numerics"]["weight_bits"]
+                or conf["numerics"]["stored_weight_bits"]),
+        },
         "peak_ops_per_s": chip[conf["numerics"]["peak"]],
         "hbm_bytes_per_s": chip["hbm_bytes_per_s"],
     }

@@ -13,10 +13,15 @@ Set-up warms every prefill width the mix can reach, decode, the merge and
 the eviction, then fills the pool with the bank's first ``slots``
 requests.  The window steps the scheduler for ``seconds``.  The check,
 after the window and with the program's state freed, teacher-forces the
-plain reference (:mod:`yardstick.lm_reference`) over a sample of the
-requests the window finished, drawn from the seed with the longest among
-them, and reads how far below the reference's best logit each served
-token's logit lies.
+plain reference over a sample of the requests the window finished, drawn
+from the seed with the longest among them, and reads how far below the
+reference's best logit each served token's logit lies.
+
+What depends on the model's architecture comes from the module the
+configuration names (``cell.arch``, ``bench/arch/<name>.py``): the
+weights' layout, the reference, and the work counted from shapes, with
+any further terms of its own (``window_work``) that its per-layer
+metrics read.  Nothing here names an architecture.
 """
 
 from __future__ import annotations
@@ -157,11 +162,12 @@ def _bad_requests(log: Log, bank: RequestBank, rids, vocab: int) -> int:
     return bad
 
 
-def _window_work(log: Log, bank: RequestBank, model: dict, num: dict):
-    """Work of the window's steps, counted from shapes: the real tokens'
-    matmul operations (prompts prefilled at their own lengths, tokens
-    decoded at their lanes' depths), and decode's least time terms."""
-    from yardstick import work
+def _window_work(arch, log: Log, bank: RequestBank, model: dict,
+                 num: dict):
+    """Work of the window's steps, counted from shapes by the architecture
+    module ``arch``: the real tokens' matmul operations (prompts
+    prefilled at their own lengths, tokens decoded at their lanes'
+    depths), and decode's least time terms."""
     act = _dtype(num["activations"]).itemsize
     wbytes = _dtype(num["weights"]).itemsize
     nbytes = _dtype(num["norm_scales"]).itemsize
@@ -175,11 +181,10 @@ def _window_work(log: Log, bank: RequestBank, model: dict, num: dict):
             if k in depths:
                 depths[k].append(n - 1 + j)
         if steps[0] in depths and n > 1:
-            prefill_flops += work.lm_tokens_flops(model, 0, n - 1,
-                                                  head=False)
-    decode_flops = [sum(work.lm_tokens_flops(model, p, 1, head=True)
+            prefill_flops += arch.tokens_flops(model, 0, n - 1, head=False)
+    decode_flops = [sum(arch.tokens_flops(model, p, 1, head=True)
                         for p in ds) for ds in depths.values()]
-    decode_bytes = [work.lm_decode_min_bytes(model, ds, act, wbytes, nbytes)
+    decode_bytes = [arch.decode_min_bytes(model, ds, act, wbytes, nbytes)
                     for ds in depths.values()]
     return prefill_flops, decode_flops, decode_bytes
 
@@ -218,10 +223,11 @@ def _teacher_rows(log: Log, bank: RequestBank, rids: list, length: int):
     return tokens, served, mask
 
 
-def _gaps(w, conf, tokens, served, mask, rows: int, lower: bool):
+def _gaps(arch, w, conf, tokens, served, mask, rows: int, lower: bool):
     """The reference's best logit less its logit of each served token, at
-    the served positions, computed ``rows`` requests at a time; with
-    ``lower`` also the same for the token the control puts first."""
+    the served positions, computed ``rows`` requests at a time by the
+    architecture module ``arch``; with ``lower`` also the same for the
+    token the control puts first."""
     model, stated = conf["model"], conf["numerics"]["weights"]
     num = lm_reference.Numerics.stated(conf["numerics"])
     served_gap, control_gap = [], []
@@ -234,10 +240,10 @@ def _gaps(w, conf, tokens, served, mask, rows: int, lower: bool):
             srv = np.concatenate([srv, np.repeat(srv[:1], pad, 0)])
         targets = [srv]
         if lower:
-            _, low_top, _ = lm_reference.evaluate(
+            _, low_top, _ = arch.evaluate(
                 w, tok, srv[None], model, num.lowered(), stated)
             targets.append(low_top)
-        best, _, picked = lm_reference.evaluate(
+        best, _, picked = arch.evaluate(
             w, tok, np.stack(targets), model, num, stated)
         m = mask[sl]
         k = len(m)
@@ -262,14 +268,14 @@ def run(cell: spec.Cell, seed: int, seconds: float, trace: bool, *, dev,
     from repro.launch import steps as program_steps
     from yardstick import harness
 
-    conf, mix = cell.config, cell.traffic
+    conf, mix, arch = cell.config, cell.traffic, cell.arch
     model = conf["model"]
     counter = harness._CompileCounter()
     rec = Log()
     try:
         cfg = program_config(conf, program_overrides)
         t_init = time.perf_counter()
-        params = lm_weights.make(seed, model, conf["numerics"],
+        params = lm_weights.make(seed, arch.shapes(model), conf["numerics"],
                                  conf["init_std"])
         lm_weights.check_layout(params, jax.eval_shape(
             lambda k: program_steps.model_module(cfg).init_params(cfg, k),
@@ -284,9 +290,16 @@ def run(cell: spec.Cell, seed: int, seconds: float, trace: bool, *, dev,
             sched = serve.lm_scheduler(max_len=bank.max_len, eos_id=None)
             _warm_up(sched, bank, rec)
             t_warm = time.perf_counter()
+            warm_done = len(rec.done)
             for rid in range(n_slots):
                 rec.submit(sched, rid, *bank.request(rid))
             rec.step(sched)
+            # a request under way may end in this first step; the loop is
+            # closed, so it is replaced at once, as in the window
+            next_rid = n_slots
+            for _ in range(len(rec.done) - warm_done):
+                rec.submit(sched, next_rid, *bank.request(next_rid))
+                next_rid += 1
             setup_s = time.perf_counter() - t_start
             log(f"set-up {setup_s:.3f} s: start and chip "
                 f"{t_init - t_start:.3f} s, weights and compile_model "
@@ -295,12 +308,14 @@ def run(cell: spec.Cell, seed: int, seconds: float, trace: bool, *, dev,
                 f"{len(bank.warm_prompt_lengths())} prefill widths "
                 f"{t_warm - t_bank:.3f} s, first join of {n_slots} slots "
                 f"{time.perf_counter() - t_warm:.3f} s")
+            before = harness.counters()
             t0 = time.perf_counter()
             with harness.profiled(trace) as trace_dir:
                 next_rid = _timed_window(
-                    sched, bank, rec, n_slots,
+                    sched, bank, rec, next_rid,
                     min(seconds, TRACE_SECONDS) if trace else seconds,
                     counter, trace)
+            counted = harness.counter_increase(before, harness.counters())
             stats = dev.memory_stats() or {}
             mem_peak = int(stats.get("peak_bytes_in_use", 0))
             del sched
@@ -325,7 +340,7 @@ def run(cell: spec.Cell, seed: int, seconds: float, trace: bool, *, dev,
     t_ref = time.perf_counter()
     rids = _sample(rec, bank, seed, int(conf["check"]["requests"]))
     tok, served, mask = _teacher_rows(rec, bank, rids, bank.max_len)
-    gap, low_gap = _gaps(params, conf, tok, served, mask,
+    gap, low_gap = _gaps(arch, params, conf, tok, served, mask,
                          int(conf["check"]["rows"]), control)
     log(f"reference over {len(rids)} requests, {int(mask.sum())} served "
         f"tokens, in {time.perf_counter() - t_ref:.3f} s; served tokens "
@@ -341,7 +356,7 @@ def run(cell: spec.Cell, seed: int, seconds: float, trace: bool, *, dev,
                       mask=mask, gap=gap,
                       **({"control.weights": low_gap} if control else {}))
     red = harness.reduce_trace(trace_dir) if trace else None
-    ctx = reader_context(cell, rec, bank, red, dev.device_kind,
+    ctx = reader_context(cell, rec, bank, red, dev.device_kind, counted,
                          log) if trace else None
     result = harness.result_line(
         cell, dev, count, mem_peak, checks, attempted=len(in_window),
@@ -409,13 +424,27 @@ def phases(red, steps: int, join_steps: int) -> dict:
 
 
 def reader_context(cell, rec: Log, bank: RequestBank, red, kind: str,
-                   log=print) -> dict:
+                   counters: dict, log=print) -> dict:
+    """What the per-layer readers of a traced LM run read: the trace's
+    reduction (``trace``), the phases of the step, ``counters`` (the
+    increase of each program counter over the window) and under ``work``
+    the window's work counted from shapes, with the architecture's own
+    terms (``window_work``) beside the harness's."""
     from yardstick import peaks
-    conf = cell.config
+    conf, arch = cell.config, cell.arch
     model, num = conf["model"], conf["numerics"]
     chip = peaks.peaks(kind)
     prefill_flops, decode_flops, decode_bytes = _window_work(
-        rec, bank, model, num)
+        arch, rec, bank, model, num)
+    work = {"model_flops": prefill_flops + sum(decode_flops),
+            "decode_flops": decode_flops, "decode_min_bytes": decode_bytes}
+    if hasattr(arch, "window_work"):
+        own = arch.window_work(rec, bank, model, num)
+        clash = sorted(set(own) & set(work))
+        if clash:
+            raise ValueError(f"window_work's terms {clash} are the "
+                             "harness's own")
+        work.update(own)
     joins = {rec.steps[r][0] for r in rec.steps
              if not isinstance(r, tuple) and rec.steps[r][0] in rec.window}
     try:
@@ -426,9 +455,7 @@ def reader_context(cell, rec: Log, bank: RequestBank, red, kind: str,
         ph = None
     return {
         "cell": cell.name, "steps": len(rec.window), "trace": red,
-        "phases": ph,
-        "model_flops": prefill_flops + sum(decode_flops),
-        "decode_flops": decode_flops, "decode_min_bytes": decode_bytes,
+        "phases": ph, "counters": counters, "work": work,
         "peak_flops_per_s": chip[num["peak"]],
         "hbm_bytes_per_s": chip["hbm_bytes_per_s"],
     }

@@ -1,12 +1,20 @@
-"""Weights for a dense decoder LM, made from the seed on the device in one
-jitted call, in the program's parameter layout and the stated dtypes.
+"""Weights for an LM, made from the seed on the device in one jitted call,
+in the program's parameter layout and the stated dtypes.
 
-Every matrix (the embedding, the attention and SwiGLU projections, the
-untied head) is normal with the standard deviation the configuration
-states (``init_std``: the published ``initializer_range``); the RMSNorm
-scales are drawn around 1 rather than left at 1, so the comparison sees
-the path that carries them.  Layers are stacked on a leading axis, as the
-program scans them.
+The layout is the architecture module's (``shapes(model)`` of
+``bench/arch/<name>.py``): ``{path: (shape, role)}``, leaves drawn in
+that order, each from a key of its own.  By role:
+
+* ``matrix``: the stated weight type, normal with the standard deviation
+  the configuration states (``init_std``: the published
+  ``initializer_range``);
+* ``scale``: a norm scale in the stated norm type, drawn around 1 as
+  ``1 + 0.1 N(0, 1)`` rather than left at 1, so the comparison sees the
+  path that carries it;
+* ``float32``: a matrix the program keeps in float32 whatever the stated
+  weight type (a router, say), normal with ``init_std``.
+
+Layers are stacked on a leading axis, as the program scans them.
 """
 
 from __future__ import annotations
@@ -15,20 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-def shapes(model: dict) -> dict:
-    """``{path: shape}`` of every leaf, layers stacked first."""
-    n, d, ff, v = (model["n_layers"], model["d_model"], model["d_ff"],
-                   model["vocab_size"])
-    q, kv = (model["n_heads"] * model["head_dim"],
-             model["n_kv_heads"] * model["head_dim"])
-    return {
-        "embed": (v, d), "lm_head": (d, v), "ln_f/scale": (d,),
-        "blocks/ln1/scale": (n, d), "blocks/ln2/scale": (n, d),
-        "blocks/attn/wq": (n, d, q), "blocks/attn/wk": (n, d, kv),
-        "blocks/attn/wv": (n, d, kv), "blocks/attn/wo": (n, q, d),
-        "blocks/mlp/w_gate": (n, d, ff), "blocks/mlp/w_up": (n, d, ff),
-        "blocks/mlp/w_down": (n, ff, d),
-    }
+ROLES = ("matrix", "scale", "float32")
 
 
 def _nest(flat: dict) -> dict:
@@ -42,27 +37,29 @@ def _nest(flat: dict) -> dict:
     return out
 
 
-def make(seed: int, model: dict, numerics: dict, init_std: float):
-    """The weights on the default device, the same for the same seed:
-    matrices of standard deviation ``init_std`` in
-    ``numerics['weights']``, norm scales in ``numerics['norm_scales']``."""
+def make(seed: int, leaves: dict, numerics: dict, init_std: float):
+    """The weights of the layout ``leaves`` (``{path: (shape, role)}``)
+    on the default device, the same for the same seed."""
     key32 = int(np.random.default_rng([int(seed) % 2 ** 64, 13])
                 .integers(0, 2 ** 31 - 1))
     std = float(init_std)
-    mat_dt = jnp.dtype(numerics["weights"])
+    dtypes = {"matrix": jnp.dtype(numerics["weights"]),
+              "float32": jnp.dtype(jnp.float32)}
     norm_dt = jnp.dtype(numerics["norm_scales"])
-    leaves = shapes(model)
+    for path, (_, role) in leaves.items():
+        if role not in ROLES:
+            raise ValueError(f"{path}: role {role!r} is none of {ROLES}")
 
     def init(key):
         keys = dict(zip(leaves, jax.random.split(key, len(leaves))))
         flat = {}
-        for path, shape in leaves.items():
-            if path.endswith("scale"):
+        for path, (shape, role) in leaves.items():
+            if role == "scale":
                 flat[path] = (1.0 + 0.1 * jax.random.normal(
                     keys[path], shape, jnp.float32)).astype(norm_dt)
             else:
                 flat[path] = (std * jax.random.normal(
-                    keys[path], shape, jnp.float32)).astype(mat_dt)
+                    keys[path], shape, jnp.float32)).astype(dtypes[role])
         return _nest(flat)
 
     return jax.jit(init)(jax.random.PRNGKey(key32))

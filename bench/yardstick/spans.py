@@ -1,18 +1,17 @@
-"""The program's own host spans in a profiler trace (``.xplane.pb``).
+"""The program's own host spans in a profiler trace (``.xplane.pb``),
+step by step.
 
 ``StreamLanes.hop`` opens ``cell.hop`` around one child span per host
 phase of a hop (``cell.hop.copy_in``, ``cell.hop.dispatch``,
 ``cell.hop.wait``, ``cell.hop.copy_out``), written into the profiler's
-host plane while it collects.  A trace recorded before the program had
-them holds no ``cell.`` span, and every function here then says so
-(``{}`` or ``None``) instead of raising.
-
-* :func:`program_seconds`: host seconds per span name, clipped to the
-  harness's ``bench_window``; the quantity a per-phase metric divides by
-  the window's steps.
-* :func:`steps`: each window step's harness ``hop`` span, the program's
-  spans inside it, and the device module that ran for it, to check that
-  the phases cover the hop and to read the host-device clock offset.
+host plane while it collects.  A run's per-phase metrics read these
+spans' seconds in the window from the trace's reduction
+(``Reduction.program_s``).  Here :func:`steps` gives each window step's
+harness ``hop`` span, the program's spans inside it and the device
+module that ran for it, to check that the phases cover the hop, and
+:func:`clock_offsets_ns` reads the host-device clock offset from them.
+A trace recorded before the program had the spans gives steps with no
+program spans and no offsets, instead of raising.
 
 Host spans are intervals on the host clock alone, so their durations do
 not depend on how the device plane is aligned to it.
@@ -24,7 +23,7 @@ import dataclasses
 
 from yardstick import trace as trace_mod
 
-PREFIX = "cell."
+PREFIX = trace_mod.PROGRAM_PREFIX
 HOP_SPAN = "cell.hop"
 HARNESS_HOP = "hop"        # the harness's span around ``StreamLanes.hop``
 PHASES = ("copy_in", "dispatch", "wait", "copy_out")
@@ -58,31 +57,6 @@ def _window(events, path) -> tuple:
         if name == trace_mod.WINDOW_SPAN:
             return s, e
     raise ValueError(f"{path}: no {trace_mod.WINDOW_SPAN!r} span")
-
-
-def program_seconds(path: str) -> dict:
-    """Host seconds of each ``cell.`` span, clipped to the window; ``{}``
-    where the trace holds none."""
-    events = _host_events(_load(path))
-    w0, w1 = _window(events, path)
-    out: dict = {}
-    for s, e, name in events:
-        if name.startswith(PREFIX):
-            s, e = max(s, w0), min(e, w1)
-            if e > s:
-                out[name] = out.get(name, 0.0) + (e - s) * 1e-9
-    return out
-
-
-def phase_ms_per_step(path: str, steps: int) -> dict | None:
-    """Mean host milliseconds of each hop phase per window step, from
-    :func:`program_seconds`; ``None`` where the trace has no program
-    spans or the window no steps."""
-    sec = program_seconds(path)
-    if not sec or steps <= 0:
-        return None
-    return {p: 1e3 * sec.get(f"{HOP_SPAN}.{p}", 0.0) / steps
-            for p in PHASES}
 
 
 def steps(path: str) -> list:

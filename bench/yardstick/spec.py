@@ -3,15 +3,20 @@
 A cell names a configuration and a traffic mix.  The configuration's file
 is the one ``BENCHMARK.json`` gives; the traffic mix is
 ``bench/traffic/<traffic>.json``; a per-layer metric is read by
-``bench/metrics/<name>.py``.  Adding a cell, a mix or a metric is adding
-files and entries: nothing here names one.
+``bench/metrics/<name>.py``; an LM configuration's architecture
+(``"architecture"`` at the file's top level) is ``bench/arch/<name>.py``.
+Adding a cell, a mix, a metric or an architecture is adding files and
+entries: nothing here names one.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import hashlib
 import importlib.util
 import json
+import sys
+import types
 from pathlib import Path
 from typing import Callable
 
@@ -30,6 +35,7 @@ class Cell:
     end_to_end: tuple        # metric entries this cell reports at --trace 0
     per_layer: tuple         # metric entries this cell reports at --trace 1
     bench: Path
+    arch: types.ModuleType | None = None   # the LM architecture module
 
 
 def load_benchmark(root: Path = ROOT) -> dict:
@@ -61,6 +67,12 @@ def load_cell(workload: str, root: Path = ROOT) -> Cell:
     with open(root / centry["file"]) as f:
         config = json.load(f)
     bench = (root / centry["file"]).parents[1]
+    if config.get("path") == "lm" and "architecture" not in config:
+        raise ValueError(f"{centry['file']}: an LM configuration names its "
+                         "architecture (\"architecture\": a module of "
+                         "bench/arch)")
+    arch = architecture(config["architecture"], bench) \
+        if "architecture" in config else None
     e2e = tuple(m for m in spec["end_to_end"] if _reports(m, workload))
     moved = {m["name"] for m in e2e}
     per_layer = tuple(m for m in spec["per_layer"]
@@ -69,7 +81,30 @@ def load_cell(workload: str, root: Path = ROOT) -> Cell:
                 traffic_name=w["traffic"],
                 traffic=load_traffic(w["traffic"], bench),
                 chips=int(w["chips"]), end_to_end=e2e, per_layer=per_layer,
-                bench=bench)
+                bench=bench, arch=arch)
+
+
+_ARCH: dict = {}
+
+
+def architecture(name: str, bench: Path = BENCH) -> types.ModuleType:
+    """The module ``bench/arch/<name>.py``, loaded once a process: an LM
+    architecture's ``shapes``, ``evaluate``, ``logits``, ``tokens_flops``,
+    ``decode_min_bytes`` and, where it has one, ``window_work``.  Raises
+    ``FileNotFoundError`` naming the path where there is no such file."""
+    path = (Path(bench) / "arch" / f"{name}.py").resolve()
+    if path not in _ARCH:
+        if not path.is_file():
+            raise FileNotFoundError(f"architecture {name!r}: no module "
+                                    f"{path}")
+        digest = hashlib.sha1(str(path).encode()).hexdigest()[:12]
+        mod_name = f"bench_arch_{digest}"
+        mod_spec = importlib.util.spec_from_file_location(mod_name, path)
+        mod = importlib.util.module_from_spec(mod_spec)
+        sys.modules[mod_name] = mod
+        mod_spec.loader.exec_module(mod)
+        _ARCH[path] = mod
+    return _ARCH[path]
 
 
 def metric_reader(name: str, bench: Path = BENCH) -> Callable:

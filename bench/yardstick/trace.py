@@ -9,7 +9,19 @@ metrics read.
   (the trace's ``tf_op``, read by :mod:`yardstick.xplane`) carries a stage
   of the stream engine (``featurise``, ``embed``, ``encode``: the
   program's ``jax.named_scope`` names); the rest, such as the copies of
-  lane state and ops that carry no name, counts as ``other``.
+  lane state and ops that carry no name, counts as ``other``.  Each op
+  counts under one stage, the first of ``SCOPES`` on its path, and the
+  breakdown names it with that stage (``encode/fusion``).
+* Named time (``named_s``) is the device time of the operations under
+  each name found anywhere on their name path, counted the same way but
+  for every name: each ``jax.named_scope`` (and JAX's own, such as
+  ``while`` and ``body`` of a loop, or an einsum's subscripts), nested
+  ones each in full.  The transforms (``jit(...)``) and the op itself,
+  the path's last part, are not names.  A new scope in the program can
+  be read here with no change to this module.
+* Program time (``program_s``) is the host seconds of each of the
+  program's own spans (``cell.`` and below, written by
+  ``repro.telemetry.span``), clipped to the window and summed by name.
 * Each idle gap of the device is labelled by the harness span
   (``prepare_chunk``, ``hop``, ``lm_step``, ``submit``) the host was in
   at its middle.
@@ -34,6 +46,7 @@ import numpy as np
 SCOPES = ("featurise", "embed", "encode")
 HOST_SPANS = ("prepare_chunk", "hop", "lm_step", "submit")
 WINDOW_SPAN = "bench_window"
+PROGRAM_PREFIX = "cell."      # the program's span namespace
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 
@@ -50,6 +63,10 @@ class Reduction:
     #                           program name -> device seconds of its ops
     module_runs: dict = dataclasses.field(default_factory=dict)
     #                           program name -> runs started in the window
+    named_s: dict = dataclasses.field(default_factory=dict)
+    #                           name on an op path -> device seconds
+    program_s: dict = dataclasses.field(default_factory=dict)
+    #                           program span name -> host seconds
 
     def top_ops(self, n: int) -> list:
         return [[k, float(v)] for k, v in
@@ -67,6 +84,24 @@ def scope_of(op_path: str) -> str:
         if s in parts:
             return s
     return "other"
+
+
+def names_on(op_path: str) -> set:
+    """The names on an op's name path: every part but the last (the op)
+    that is no transform such as ``jit(joint)``."""
+    return {p for p in op_path.split("/")[:-1]
+            if p and not (p.endswith(")") and "(" in p)}
+
+
+def _clipped_seconds(spans, w0: float, w1: float) -> dict:
+    """Seconds of ``(start_ns, end_ns, name)`` spans inside [w0, w1),
+    summed by name."""
+    out: dict = {}
+    for s, e, name in spans:
+        s, e = max(s, w0), min(e, w1)
+        if e > s:
+            out[name] = out.get(name, 0.0) + (e - s) * 1e-9
+    return out
 
 
 def short_name(hlo_text: str) -> str:
@@ -97,7 +132,7 @@ def reduce_file(path: str) -> Reduction:
     from yardstick import xplane
     pd = ProfileData.from_file(path)
     op_path = xplane.op_names(path)
-    window, spans = None, []
+    window, spans, program = None, [], []
     device_ops, device_runs = [], []
     for plane in pd.planes:
         if plane.name.startswith("/host:"):
@@ -108,6 +143,9 @@ def reduce_file(path: str) -> Reduction:
                     elif ev.name in HOST_SPANS:
                         spans.append((ev.start_ns, ev.start_ns
                                       + ev.duration_ns, ev.name))
+                    elif ev.name.startswith(PROGRAM_PREFIX):
+                        program.append((ev.start_ns, ev.start_ns
+                                        + ev.duration_ns, ev.name))
         elif plane.name.startswith("/device:"):
             evs, runs = [], []
             for line in plane.lines:
@@ -127,6 +165,7 @@ def reduce_file(path: str) -> Reduction:
     spans.sort()
     span_starts = np.asarray([s[0] for s in spans], np.float64)
     busy, scope_s = 0.0, collections.Counter()
+    named_s = collections.Counter()
     ops, gaps = collections.Counter(), collections.Counter()
     module_s, module_runs = collections.Counter(), collections.Counter()
     for evs, runs in zip(device_ops, device_runs):
@@ -142,6 +181,8 @@ def reduce_file(path: str) -> Reduction:
             sec = (e - s) * 1e-9
             scope = scope_of(path)
             scope_s[scope] += sec
+            for named in names_on(path):
+                named_s[named] += sec
             ops[f"{scope}/{short_name(name)}"] += sec
             if r >= 0:
                 run_iv[r].append((s, e))
@@ -165,7 +206,9 @@ def reduce_file(path: str) -> Reduction:
                      gaps={k: v / n for k, v in gaps.items()},
                      devices=len(device_ops),
                      module_s={k: v / n for k, v in module_s.items()},
-                     module_runs={k: v / n for k, v in module_runs.items()})
+                     module_runs={k: v / n for k, v in module_runs.items()},
+                     named_s={k: v / n for k, v in named_s.items()},
+                     program_s=_clipped_seconds(program, w0, w1))
 
 
 def reduce_dir(trace_dir: str) -> Reduction:
