@@ -9,11 +9,16 @@ scheduler replaces it with true continuous batching:
   (``models.transformer``: cache writes scatter at ``[lane, idx[lane]]``,
   RoPE positions and validity bounds are per-lane), so every lane decodes
   at its own depth;
-* joiners prefill into a FRESH decode state (ordinary scalar-index
-  prefill of the right-padded prompt minus its last token) which is then
-  merged per-lane into the live state
-  (``transformer.merge_decode_state``) — resident lanes never stop
-  decoding and their caches are untouched;
+* joiners prefill straight into the live decode state, one program a
+  join step (``Engine.join``): each joiner's right-padded prompt minus
+  its last token is prefilled ALONE, at batch 1 into a fresh cache of
+  the join's width, and written into its lane's slots ``0..w``
+  (``transformer.merge_decode_state``), in place since the live state
+  is donated.  No pad row is computed, resident lanes never stop
+  decoding and their caches are untouched; slots past ``w`` keep the
+  lane's previous request's keys, which the per-lane validity bound
+  masks as it masks pad keys.  On moe a joiner's routing is its own,
+  never shared with its co-joiners';
 * the first ``decode_step`` after a join feeds the prompt's LAST token,
   writing its KV at slot ``len-1`` under the lane's own position — from
   then on the lane is indistinguishable from one that prefilled alone.
@@ -37,11 +42,8 @@ import dataclasses
 import time
 from typing import Any, Optional
 
-import jax
 import jax.numpy as jnp
 import numpy as np
-
-from repro.models import transformer
 
 
 @dataclasses.dataclass(frozen=True)
@@ -75,9 +77,9 @@ class LMScheduler:
     """A fixed pool of ``slots`` decode lanes with in-flight join/evict.
 
     Drive with ``submit`` + repeated ``step``; each ``step`` joins
-    waiting requests into free lanes (one batched fresh prefill, no
-    drain), advances EVERY lane one greedy token, and evicts lanes whose
-    request hit EOS or its budget.  Evicted lanes keep decoding garbage
+    waiting requests into free lanes (one join program, no drain),
+    advances EVERY lane one greedy token, and evicts lanes whose request
+    hit EOS or its budget.  Evicted lanes keep decoding garbage
     until re-joined (the batch shape is static); their outputs are
     discarded and their per-lane index is parked at 0 so cache scatters
     stay in bounds.
@@ -99,12 +101,11 @@ class LMScheduler:
         self.slots, self.max_len, self.eos_id = slots, max_len, eos_id
         self.prefill_len = prefill_len      # None -> per-group pow2 bucket
         self.metrics = metrics
-        self._merge = jax.jit(transformer.merge_decode_state)
         self.queue: list[Request] = []
         self.active: list[Optional[Request]] = [None] * slots
         self._remaining = np.zeros(slots, np.int64)
         self.state = self._engine(engine).init_decode_state(slots, max_len)
-        # per-lane depth from step one (scalar would retrace on first merge)
+        # per-lane depth from step one (the join program's carry is [B])
         self.state["index"] = jnp.zeros((slots,), jnp.int32)
         self._cur = jnp.zeros((slots,), jnp.int32)
 
@@ -191,35 +192,34 @@ class LMScheduler:
         if not joins:
             return
         eng, met = self.engine, self.metrics
-        B = self.slots
         # right-pad prompts MINUS their last token; the first decode_step
         # feeds that token, so real token j always sits at cache slot j
         # with position j and pad keys are masked by the per-lane validity
         # bound — lane results don't depend on co-joiners' prompts.
-        lens = {i: len(r.prompt) for i, r in joins}
-        plen = self.prefill_len or _bucket(max(max(lens.values()) - 1, 1))
-        assert plen >= max(lens.values()) - 1, \
+        lens = [len(r.prompt) for _, r in joins]
+        plen = self.prefill_len or _bucket(max(max(lens) - 1, 1))
+        assert plen >= max(lens) - 1, \
             f"prefill_len={plen} shorter than a submitted prompt"
-        toks = np.zeros((B, plen), np.int32)
+        # joiners packed first; shapes depend on the width alone
+        toks = np.zeros((self.slots, plen), np.int32)
+        lanes = np.zeros(self.slots, np.int32)
         cur, idx = np.asarray(self._cur).copy(), \
             np.asarray(self.state["index"]).copy()
-        mask = np.zeros(B, bool)
-        for i, req in joins:
-            toks[i, :lens[i] - 1] = req.prompt[:-1]
+        for j, (i, req) in enumerate(joins):
+            toks[j, :lens[j] - 1] = req.prompt[:-1]
+            lanes[j] = i
             cur[i] = req.prompt[-1]
-            idx[i] = lens[i] - 1
-            mask[i] = True
+            idx[i] = lens[j] - 1
             self.active[i] = req
             self._remaining[i] = req.max_new
         t0 = time.perf_counter()
-        fresh = eng.init_decode_state(B, self.max_len)
-        _, fresh = eng.prefill(jnp.asarray(toks), fresh)
-        merged = self._merge(self.state, fresh, jnp.asarray(mask))
-        merged["index"] = jnp.asarray(idx, jnp.int32)
-        self.state = merged
+        state = eng.join(toks, lanes, np.int32(len(joins)), self.state)
+        state["index"] = jnp.asarray(idx, jnp.int32)
+        self.state = state
         self._cur = jnp.asarray(cur)
         if met is not None:
             met.prefill_ms.observe(1e3 * (time.perf_counter() - t0))
             met.joins.inc(len(joins))
-            met.prefill_tokens.inc(int(sum(lens.values())))
+            met.prefill_tokens.inc(int(sum(lens)))
+            met.prefill_positions.inc(len(joins) * plen)
             met.queue_depth.set(len(self.queue))

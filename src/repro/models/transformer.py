@@ -274,8 +274,7 @@ def prefill(params, tokens, cfg, state):
             # Cache writes scatter at [lane, idx[lane]]; positions and the
             # validity bound are per-lane (layers._sdpa_block broadcasts).
             assert s == 1, "per-lane decode state advances one token at " \
-                "a time; joins prefill a fresh state and merge " \
-                "(cell.scheduler)"
+                "a time; joins prefill each lane alone (join_lanes)"
             positions = idx[:, None] + jnp.arange(s)
         else:
             positions = idx + jnp.arange(s)
@@ -321,24 +320,45 @@ def decode_step(params, token, cfg, state):
     return prefill(params, token[:, None], cfg, state)
 
 
-def merge_decode_state(old, new, lane_mask):
-    """Per-lane select between two same-shaped decode states.
+def merge_decode_state(old, new, lane):
+    """Write the prefilled one-lane state ``new`` into ``old`` at ``lane``.
 
-    The join half of continuous batching (cell.scheduler): freshly
-    prefilled lanes take ``new``'s cache/recurrence and index, resident
-    lanes keep ``old``'s — no drain barrier.  Every ``layers`` leaf is
-    stacked ``[n_layers, B, ...]`` (batch at axis 1); ``index`` may be
-    scalar on either side and merges to a per-lane [B] vector.
+    The join half of continuous batching (cell.scheduler): every
+    ``layers`` leaf of ``new`` is stacked ``[n_layers, 1, ...]`` and no
+    longer than ``old``'s along any axis; it lands on ``lane`` from the
+    start of every later axis, so a cache of ``w`` slots fills slots
+    ``0..w`` and later slots keep what they held.  Other lanes are
+    untouched: resident lanes never stop decoding.  ``old``'s ``index``
+    is the per-lane [B] vector; ``lane``'s takes ``new``'s index.
     """
-    def sel(n, o):
-        m = lane_mask.reshape((1, lane_mask.shape[0]) + (1,) * (n.ndim - 2))
-        return jnp.where(m, n, o)
+    def put(o, n):
+        return jax.lax.dynamic_update_slice(
+            o, n, (0, lane) + (0,) * (o.ndim - 2))
 
-    index = jnp.where(lane_mask,
-                      jnp.broadcast_to(new["index"], lane_mask.shape),
-                      jnp.broadcast_to(old["index"], lane_mask.shape))
-    return {"layers": jax.tree.map(sel, new["layers"], old["layers"]),
-            "index": index}
+    return {"layers": jax.tree.map(put, old["layers"], new["layers"]),
+            "index": old["index"].at[lane].set(new["index"])}
+
+
+def join_lanes(params, tokens, lanes, n, cfg, state):
+    """Prefill the first ``n`` rows of ``tokens`` [slots, w] into lanes
+    ``lanes[:n]`` of the live per-lane decode ``state``.
+
+    Each row is prefilled alone, at batch 1 into a fresh ``w``-slot
+    state, so attention spans ``w`` keys and no pad row is computed; it
+    is then written into its lane through ``merge_decode_state`` (looked
+    up at trace time).  The live state is the loop's carry: a caller
+    that donates it has it updated in place.  Shapes depend on ``w``
+    alone (``n`` is an array), so one program serves every joiner count.
+    A joiner's routing (moe) is its own, never shared with co-joiners.
+    """
+    w = tokens.shape[1]
+
+    def body(i, st):
+        row = jax.lax.dynamic_slice_in_dim(tokens, i, 1, axis=0)
+        _, fresh = prefill(params, row, cfg, init_decode_state(cfg, 1, w))
+        return merge_decode_state(st, fresh, lanes[i])
+
+    return jax.lax.fori_loop(0, n, body, state)
 
 
 def forward_no_blocks(params, tokens, cfg):

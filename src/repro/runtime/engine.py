@@ -15,7 +15,8 @@ entry points all run that one plan:
     state, logits = eng.stream_step(state, chunk, fcfg)
 
 LM families additionally expose ``init_decode_state`` / ``prefill`` /
-``decode_step`` so ``launch/serve.py`` runs off the same object.
+``decode_step`` so ``launch/serve.py`` runs off the same object, and
+``join`` for ``cell.scheduler``'s in-flight joins.
 
 Contract (tests/test_runtime.py): for any backend, streaming logits are
 bit-identical to that same engine's offline ``forward`` on the matching
@@ -85,6 +86,7 @@ class Engine:
         cfg = self.exec_cfg
         self._forward = jax.jit(lambda p, x: self._mod.forward(p, x, cfg))
         self._embed = self._encode = self._prefill = self._decode = None
+        self._join = None
         self._stream_steps = {}
         self._taps_fn = None
         self._unpack = jax.jit(quant.dequantize_tree) \
@@ -254,6 +256,34 @@ class Engine:
             lp = self._live_traced(tr)
             with tr.span("encode"):
                 return jax.block_until_ready(self._prefill(lp, tokens, state))
+
+    def join(self, tokens, lanes, n, state):
+        """Prefill joining requests into the live per-lane decode state.
+
+        ``tokens`` [slots, w] holds the joiners' right-padded prompts
+        (less their last token) packed first, ``lanes`` [slots] their
+        lanes and ``n`` (an int32 array) how many there are; each is
+        prefilled alone at batch 1 into slots ``0..w`` of its lane
+        (``transformer.join_lanes``), so the program compiles once per
+        width and computes no pad row.  ``state`` is donated off the
+        CPU: the caller keeps only the returned state.  On moe a
+        joiner's routing is its own, not shared with its co-joiners'.
+        """
+        if self._join is None:
+            cfg = self.exec_cfg
+            # CPU jax ignores donation with a warning; stay quiet there
+            donate = (4,) if jax.default_backend() != "cpu" else ()
+            self._join = jax.jit(
+                lambda p, t, ln, k, s: self._mod.join_lanes(
+                    p, t, ln, k, cfg, s), donate_argnums=donate)
+        tr = _trace.active_tracer()
+        if tr is None:
+            return self._join(self.live_params(), tokens, lanes, n, state)
+        with tr.span("join", {"backend": self.backend.name}):
+            lp = self._live_traced(tr)
+            with tr.span("encode"):
+                return jax.block_until_ready(
+                    self._join(lp, tokens, lanes, n, state))
 
     def decode_step(self, token, state):
         if self._decode is None:

@@ -58,6 +58,7 @@ class CellMetrics:
     dropped_hops: Counter     # cell_dropped_hops_total (MUST stay 0)
     tokens: Counter           # cell_tokens_total (LM tokens decoded)
     prefill_tokens: Counter   # cell_prefill_tokens_total (joined prompts)
+    prefill_positions: Counter  # cell_prefill_positions_total (computed)
     hop_ms: Histogram         # cell_hop_latency_ms
     hop_phase_s: dict         # phase -> cell_hop_phase_seconds_total{phase}
     hop_bytes_in: Counter     # cell_hop_transfer_bytes_total{direction="in"}
@@ -102,6 +103,9 @@ def make_cell_metrics(registry: Registry) -> CellMetrics:
         tokens=registry.counter("cell_tokens_total", "LM tokens decoded"),
         prefill_tokens=registry.counter("cell_prefill_tokens_total",
                                         "prompt tokens prefilled at join"),
+        prefill_positions=registry.counter(
+            "cell_prefill_positions_total",
+            "positions the join programs computed: joiners x join width"),
         hop_ms=registry.histogram("cell_hop_latency_ms",
                                   "stream hop wall time", unit="ms"),
         hop_phase_s={p: registry.counter("cell_hop_phase_seconds_total",

@@ -75,20 +75,27 @@ def test_vector_index_decode_matches_scalar(lm_engine):
 
 
 def test_merge_decode_state_selects_per_lane(lm_engine):
+    """A prefilled lane state lands on its lane from slot 0: later slots
+    of that lane keep what they held, and every other lane is bit for bit
+    what it was."""
     eng = lm_engine
-    old = eng.init_decode_state(2, 8)
-    new = eng.init_decode_state(2, 8)
-    old["index"] = jnp.asarray([3, 5], jnp.int32)
-    new["index"] = jnp.asarray([0, 0], jnp.int32)
-    new["layers"] = jax.tree.map(
-        lambda a: a + 1 if jnp.issubdtype(a.dtype, jnp.floating) else a,
-        new["layers"])
-    merged = transformer.merge_decode_state(old, new,
-                                            jnp.asarray([False, True]))
-    np.testing.assert_array_equal(np.asarray(merged["index"]), [3, 0])
-    k = jax.tree.leaves(merged["layers"])[0]       # [n_layers, B, ...]
-    assert float(jnp.sum(jnp.abs(k[:, 0].astype(jnp.float32)))) == 0.0
-    assert float(jnp.sum(jnp.abs(k[:, 1].astype(jnp.float32)))) > 0.0
+    old = eng.init_decode_state(3, 8)
+    old["layers"] = jax.tree.map(
+        lambda a: jax.random.normal(jax.random.PRNGKey(a.ndim), a.shape
+                                    ).astype(a.dtype), old["layers"])
+    old["index"] = jnp.asarray([3, 5, 7], jnp.int32)
+    new = eng.init_decode_state(1, 4)
+    new["layers"] = jax.tree.map(lambda a: a + 1, new["layers"])
+    new["index"] = jnp.asarray(4, jnp.int32)
+    merged = transformer.merge_decode_state(old, new, 1)
+    np.testing.assert_array_equal(np.asarray(merged["index"]), [3, 4, 7])
+    for m, o, n in zip(jax.tree.leaves(merged["layers"]),
+                       jax.tree.leaves(old["layers"]),
+                       jax.tree.leaves(new["layers"])):
+        m, o, n = np.asarray(m), np.asarray(o), np.asarray(n)
+        np.testing.assert_array_equal(m[:, [0, 2]], o[:, [0, 2]])
+        np.testing.assert_array_equal(m[:, 1, :4], n[:, 0])
+        np.testing.assert_array_equal(m[:, 1, 4:], o[:, 1, 4:])
 
 
 # ---------------------------------------------------------------------------
@@ -172,6 +179,68 @@ def test_scheduler_metrics_ledger(lm_engine):
     assert met.joins.value == 3 and met.evictions.value == 3
     assert met.tokens.value == sum(len(v) for v in out.values())
     assert met.prefill_tokens.value == sum(len(p) for _, p, _ in reqs)
+
+
+def test_join_into_a_vacated_lane_ignores_its_stale_keys(lm_engine):
+    """A request that joins the lane a LONGER request left (its keys still
+    in the slots past the join width) is served the same tokens as in a
+    fresh scheduler: the per-lane validity bound masks what it left."""
+    rng = np.random.RandomState(3)
+    vocab = lm_engine.cfg.vocab_size
+    long_p, fill_p, short_p = (rng.randint(0, vocab, size=n)
+                               for n in (11, 5, 3))
+    fresh = cellmod.LMScheduler(lm_engine, slots=2, max_len=64,
+                                prefill_len=16)
+    fresh.submit("c", short_p, 25)
+    want = fresh.run()["c"]
+
+    s = cellmod.LMScheduler(lm_engine, slots=2, max_len=64, prefill_len=16)
+    s.submit("long", long_p, 45)         # lane 0, to slot 54
+    s.submit("fill", fill_p, 50)         # lane 1, outlasts "long"
+    s.submit("c", short_p, 25)           # waits, then takes lane 0
+    lane_of_c = None
+    out = {}
+    while not s.idle():
+        for ev in s.step():
+            out.setdefault(ev.rid, []).append(ev.token)
+        if lane_of_c is None and "c" in out:
+            lane_of_c = [r is not None and r.rid == "c"
+                         for r in s.active].index(True)
+    assert lane_of_c == 0
+    assert out["c"] == want
+
+
+def test_join_compiles_once_per_width(lm_engine):
+    """The join program's shapes depend on the width alone: after one
+    joiner at a width, three joiners at that width compile nothing."""
+    reqs = _requests(lm_engine.cfg, n=4, seed=5)
+    s = cellmod.LMScheduler(lm_engine, slots=4, max_len=48, prefill_len=16)
+    s.submit(*reqs[0][:2], 30)
+    s.step()
+    compiled = lm_engine._join._cache_size()
+    for rid, p, g in reqs[1:]:
+        s.submit(rid, p, g)
+    s.step()
+    assert s.n_active == 4
+    assert lm_engine._join._cache_size() == compiled
+
+
+def test_prefill_positions_count_joiners_times_width(lm_engine):
+    """``cell_prefill_positions_total`` grows by joiners x join width a
+    join, ``cell_prefill_tokens_total`` by the joiners' real lengths."""
+    met = _metrics()
+    s = cellmod.LMScheduler(lm_engine, slots=2, max_len=64, metrics=met)
+    rng = np.random.RandomState(7)
+    vocab = lm_engine.cfg.vocab_size
+    s.submit(0, rng.randint(0, vocab, size=5), 3)
+    s.submit(1, rng.randint(0, vocab, size=9), 6)
+    s.submit(2, rng.randint(0, vocab, size=20), 2)
+    s.step()                             # joins 0 and 1 at width 8
+    assert met.prefill_positions.value == 2 * 8
+    assert met.prefill_tokens.value == 5 + 9
+    s.run()                              # 2 joins alone at width 32
+    assert met.prefill_positions.value == 2 * 8 + 32
+    assert met.prefill_tokens.value == 5 + 9 + 20
 
 
 def test_scheduler_rejects_recurrent_families():
